@@ -6,8 +6,9 @@ backends") and runs twice here:
 * **sim** — the discrete-time coherence machine prices every micro-op
   with a ``CostModel`` and reports episodes per kilocycle (model time);
 * **measured** — the same IR as a Pallas kernel over the device atomics
-  layer reports episodes per wall-second and per kilo-slice (real time;
-  interpret mode on CPU, compiled kernels on an accelerator).
+  layer reports episodes per wall-second and per kilo-slice (compiled
+  for the TPU; ``--interpret`` runs the Pallas interpreter instead, and
+  its wall times are then the interpreter's).
 
 Two things to watch in the output:
 
@@ -24,6 +25,7 @@ Two things to watch in the output:
    ``bench/calibrate.py`` fits.
 
 Run: PYTHONPATH=src python examples/measured_vs_sim.py [--threads 4]
+     [--interpret]
 """
 import argparse
 
@@ -40,6 +42,9 @@ def main():
     ap = argparse.ArgumentParser(description=__doc__)
     ap.add_argument("--threads", type=int, default=4)
     ap.add_argument("--rounds", type=int, default=800)
+    ap.add_argument("--interpret", action="store_true",
+                    help="run the kernel in the Pallas interpreter "
+                         "(needed on a host without a TPU)")
     args = ap.parse_args()
     T, rounds = args.threads, args.rounds
     sim_steps = rounds * T                    # same op budget per tier
@@ -58,7 +63,7 @@ def main():
         prog = PROGRAMS[name](T, ncs_max=0, cs_shared=True)
         s_def = run_machine(prog, T, sim_steps, cm=CostModel(), seed=0)
         s_uni = run_machine(prog, T, sim_steps, cm=uni, seed=0)
-        r = run_measured(name, T, rounds)
+        r = run_measured(name, T, rounds, interpret=args.interpret)
         orders[name] = (
             np.asarray(s_uni.adm_log)[:int(s_uni.adm_cnt)][:16].tolist(),
             r.admissions[:min(r.admission_counts, 16)].tolist())

@@ -100,10 +100,10 @@ def _feed_jaxpr(h, jaxpr, ids) -> None:
     the structure and always recursing into nested jaxprs removes that
     dependence. ``ids`` numbers variables in first-encounter order so
     dataflow (not object identity) is what's hashed."""
-    import jax
+    from jax.extend.core import Literal
 
     def ref(v):
-        if isinstance(v, jax.core.Literal):
+        if isinstance(v, Literal):
             _feed(h, "lit", v.aval)
             _feed_array(h, v.val)
             return
@@ -131,12 +131,12 @@ def _feed_jaxpr(h, jaxpr, ids) -> None:
 
 
 def _feed_jaxpr_param(h, p, ids) -> None:
-    import jax
-    if isinstance(p, jax.core.ClosedJaxpr):
+    from jax.extend.core import ClosedJaxpr, Jaxpr
+    if isinstance(p, ClosedJaxpr):
         _feed_jaxpr(h, p.jaxpr, dict(ids))
         for c in p.consts:
             _feed_array(h, c)
-    elif isinstance(p, jax.core.Jaxpr):
+    elif isinstance(p, Jaxpr):
         _feed_jaxpr(h, p, dict(ids))
     elif isinstance(p, (tuple, list)):
         _feed(h, "seq", len(p))

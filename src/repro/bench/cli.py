@@ -55,6 +55,7 @@ import time
 
 from repro.bench import cache as cachemod
 from repro.bench import registry, report, schema
+from repro.compile_cache import configure_compile_cache
 
 DEFAULT_REPORT = "docs/RESULTS.md"
 DEFAULT_TREND = "BENCH_trend.json"
@@ -87,6 +88,7 @@ def _build_config(args) -> registry.BenchConfig:
     kw["seed0"] = args.seed
     kw["quick"] = args.quick
     kw["verbose"] = not args.no_progress
+    kw["interpret"] = args.interpret
     return registry.BenchConfig(**kw)
 
 
@@ -187,8 +189,8 @@ def cmd_list(args) -> int:
         for row in backends():
             mark = "available" if row["available"] else "UNAVAILABLE"
             print(f"{row['name']:17s} {mark:12s} {row['detail']}")
-        print(f"{'':17s} the `measured` suite auto-selects "
-              "pallas-device when present, else pallas-interpret")
+        print(f"{'':17s} the `measured` suite runs pallas-device, or "
+              "pallas-interpret under `run --interpret`")
     if show_properties:
         from repro.core.locks import verify as verify_mod
         print("# verified/declared lock properties (structural analysis "
@@ -371,6 +373,10 @@ def build_parser() -> argparse.ArgumentParser:
                           f"{DEFAULT_TREND} next to --out)")
     run.add_argument("--no-trend", action="store_true",
                      help="skip the trend-log append")
+    run.add_argument("--interpret", action="store_true",
+                     help="run the measured tier's Pallas kernels in the "
+                          "interpreter (default: compile for the TPU, and "
+                          "fail without one)")
     run.set_defaults(fn=cmd_run)
 
     rep = sub.add_parser("report",
@@ -410,6 +416,7 @@ def build_parser() -> argparse.ArgumentParser:
 
 def main(argv=None) -> int:
     args = build_parser().parse_args(argv)
+    configure_compile_cache()
     try:
         return args.fn(args)
     except registry.UnknownSuiteError as e:

@@ -4,18 +4,20 @@ Runs the Fig. 1-3 style throughput/latency sweeps with *wall-clock*
 time instead of model cycles: every cell is one
 ``core/locks/pallas_backend.run_measured`` launch — the same ``LockIR``
 the sim executes, lowered to a kernel that hammers the lock words
-through the device atomics layer. On CI (no accelerator) the cells run
-in Pallas interpret mode: schedule-exact, linearizable, slow — so the
-wall numbers are an interpreter proxy while the *structure* (admission
-order, episode split, mutual exclusion) is the real thing, and the
-backend-agreement table cross-checks it against the sim at uniform
-cost.
+through the device atomics layer. Cells compile for the TPU the process
+holds and fail on any other backend. ``BenchConfig.interpret``
+(``repro.bench run --interpret``) runs them in the Pallas interpreter
+instead, as the CPU tests do: schedule-exact and linearizable, but the
+wall numbers then time the interpreter, and every cell records its
+``platform`` and ``device_kind`` so they cannot pass for device times.
+The backend-agreement table cross-checks the admission structure
+against the sim at uniform cost in either mode.
 
 Cells are fronted by the experiment cache under a dedicated
 ``"measured"`` key kind (``_measured_key``): the key starts from the
 same program fingerprint as sim cells but never collides with the sim
-``"cell"`` keyspace, and bakes in the backend mode so interpret and
-device runs cache separately. Cache hit/miss accounting flows through
+``"cell"`` keyspace, and bakes in the backend mode and the device kind,
+so interpret runs and runs on different chips cache separately. Cache hit/miss accounting flows through
 ``store.stats`` like every other cell, so suite-level telemetry
 (``BENCH_trend.json`` wall/traces/hit-rate) covers measured runs with
 no extra plumbing.
@@ -60,7 +62,7 @@ def _rounds(cfg: BenchConfig, n_threads: int) -> int:
 
 
 def _measured_key(ir, n_threads: int, rounds: int, seed: int,
-                  interpret: bool) -> str:
+                  interpret: bool, device_kind: str) -> str:
     """Content key of a measured cell. Distinct key *kind* from the sim
     ``"cell"`` keyspace (bench/cache.py) — a measured run and a sim run
     of the same program can never collide."""
@@ -69,24 +71,24 @@ def _measured_key(ir, n_threads: int, rounds: int, seed: int,
         {"v": cachemod.CACHE_KEY_VERSION, "kind": "measured", "fp": fp,
          "T": int(n_threads), "rounds": int(rounds), "seed": int(seed),
          "ncs": int(ir.ncs_max), "cs": ir.cs_mode,
-         "backend": "interpret" if interpret else "device"},
+         "backend": "interpret" if interpret else "device",
+         "device": device_kind},
         sort_keys=True).encode()).hexdigest()
 
 
 def measured_cell(alg: str, n_threads: int, rounds: int, *,
                   ncs_max: int = 0, cs_shared=True, seed: int = 0,
-                  interpret: bool | None = None) -> dict:
+                  interpret: bool = False) -> dict:
     """One measured cell, cache-fronted. Returns the summary dict (not
     the ``MeasuredResult`` — the cache stores plain JSON)."""
     import jax
 
     from repro.core.locks.pallas_backend import resolve_ir, run_measured
 
-    if interpret is None:
-        interpret = jax.default_backend() == "cpu"
     ir = resolve_ir(alg, n_threads, ncs_max=ncs_max, cs_shared=cs_shared)
     store = cachemod.get_cache()
-    key = _measured_key(ir, n_threads, rounds, seed, interpret)
+    key = _measured_key(ir, n_threads, rounds, seed, interpret,
+                        jax.devices()[0].device_kind)
     s = store.get(key)
     if s is not None:
         if store.enabled:
@@ -97,7 +99,9 @@ def measured_cell(alg: str, n_threads: int, rounds: int, *,
     r = run_measured(ir, n_threads, rounds, seed=seed, interpret=interpret)
     s = {
         "lock": r.name, "threads": n_threads, "rounds": rounds,
-        "backend": r.backend, "episodes": r.episodes,
+        "backend": r.backend, "platform": r.platform,
+        "device_kind": r.device_kind, "device_count": r.device_count,
+        "episodes": r.episodes,
         "per_thread": r.per_thread.tolist(),
         "collisions": r.collisions, "returns": r.returns,
         "aborts": r.aborts, "admission_counts": r.admission_counts,
@@ -122,7 +126,8 @@ def measured_sweep(algs, cfg: BenchConfig, *, ncs_max: int = 0,
         for t in cfg.threads:
             t0 = time.time()
             c = measured_cell(alg, t, _rounds(cfg, t), ncs_max=ncs_max,
-                              cs_shared=cs_shared, seed=cfg.seed0)
+                              cs_shared=cs_shared, seed=cfg.seed0,
+                              interpret=cfg.interpret)
             wall = time.time() - t0
             if on_cell is not None:
                 on_cell(alg, t, c)
@@ -145,41 +150,55 @@ def measured_sweep(algs, cfg: BenchConfig, *, ncs_max: int = 0,
 
 # --- backend-agreement differential ------------------------------------------
 
-def agreement_rows(cfg: BenchConfig, algs=AGREEMENT_ALGS,
-                   n_threads: int = 3) -> list:
-    """The backend-agreement harness: the sim under a *uniform* cost
-    model (hit == miss == 1 cycle) dispatches exactly the measured
-    kernel's round-robin op schedule, so both backends must produce the
-    same admission order and, over the compared admission prefix, the
-    same per-thread CS counts. A mismatch means one backend's machine
-    semantics drifted."""
+def sim_agreement(alg: str, n_threads: int, admissions,
+                  admission_counts: int, *, sim_steps: int, seed: int = 0,
+                  limit: int = 48) -> dict:
+    """The backend-agreement check: the sim under a *uniform* cost model
+    (hit == miss == 1 cycle) dispatches exactly the measured kernel's
+    round-robin op schedule, so a measured admission ring must equal the
+    sim's admission order and, over the compared prefix (at most
+    ``limit`` admissions), its per-thread CS counts. A mismatch means one
+    backend's machine semantics drifted."""
     from repro.core.locks.programs import PROGRAMS
-    from repro.core.sim.machine import run_machine
+    from repro.core.sim.machine import ADM_LOG, run_machine
 
     uni = CostModel(hit=1, local_miss=1, remote_miss=1)
+    prog = PROGRAMS[alg](n_threads, ncs_max=0, cs_shared=True)
+    s = run_machine(prog, n_threads, sim_steps, cm=uni, seed=seed)
+    if int(s.adm_cnt) > ADM_LOG:
+        raise ValueError(f"{alg}: {int(s.adm_cnt)} sim admissions wrap the "
+                         f"{ADM_LOG}-entry log; lower sim_steps")
+    sim_order = np.asarray(s.adm_log)[:int(s.adm_cnt)]
+    pal_order = np.asarray(admissions)[:admission_counts]
+    n = min(len(sim_order), len(pal_order), limit)
+    sim_cnt = np.bincount(sim_order[:n], minlength=n_threads)
+    pal_cnt = np.bincount(pal_order[:n], minlength=n_threads)
+    return {
+        "compared": n,
+        "order_match": bool((sim_order[:n] == pal_order[:n]).all()),
+        "cs_counts_match": bool((sim_cnt == pal_cnt).all()),
+        "cs_split": "/".join(str(int(x)) for x in pal_cnt),
+    }
+
+
+def agreement_rows(cfg: BenchConfig, algs=AGREEMENT_ALGS,
+                   n_threads: int = 3) -> list:
+    """:func:`sim_agreement` for each of ``algs``, one table row each."""
     sim_steps = 1_000 if cfg.quick else 3_000
     rounds = 150 if cfg.quick else 400
     rows = []
     for alg in algs:
-        prog = PROGRAMS[alg](n_threads, ncs_max=0, cs_shared=True)
-        s = run_machine(prog, n_threads, sim_steps, cm=uni, seed=cfg.seed0)
-        sim_order = np.asarray(s.adm_log)[:int(s.adm_cnt)].tolist()
-        c = measured_cell(alg, n_threads, rounds, seed=cfg.seed0)
-        pal_order = c["admissions"][:c["admission_counts"]]
-        n = min(len(sim_order), len(pal_order), 48)
-        match = sim_order[:n] == pal_order[:n]
-        sim_cnt = np.bincount(sim_order[:n], minlength=n_threads)
-        pal_cnt = np.bincount(pal_order[:n], minlength=n_threads)
-        rows.append({
-            "lock": alg, "threads": n_threads, "compared": n,
-            "order_match": bool(match),
-            "cs_counts_match": bool((sim_cnt == pal_cnt).all()),
-            "cs_split": "/".join(str(int(x)) for x in pal_cnt),
-            "collisions": c["collisions"],
-        })
+        c = measured_cell(alg, n_threads, rounds, seed=cfg.seed0,
+                          interpret=cfg.interpret)
+        a = sim_agreement(alg, n_threads, c["admissions"],
+                          c["admission_counts"], sim_steps=sim_steps,
+                          seed=cfg.seed0)
+        rows.append({"lock": alg, "threads": n_threads, **a,
+                     "collisions": c["collisions"]})
         if cfg.verbose:
             emit(f"measured_agree/{alg}", 0.0,
-                 f"order_match={match} n={n} coll={c['collisions']}")
+                 f"order_match={a['order_match']} n={a['compared']} "
+                 f"coll={c['collisions']}")
     return rows
 
 
@@ -196,8 +215,8 @@ def build_measured(cfg: BenchConfig) -> list:
         ("name", "available", "detail"),
         [dict(r) for r in backends()],
         meta={"note": "`repro.bench list --backends` prints this "
-                      "catalogue; measured cells auto-select "
-                      "pallas-device when an accelerator is present."})]
+                      "catalogue; measured cells run pallas-device, or "
+                      "pallas-interpret under --interpret."})]
 
     algs = _algs(cfg)
     meas: dict = {}

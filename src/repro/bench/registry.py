@@ -26,6 +26,7 @@ class BenchConfig:
     quick: bool = False
     algs: tuple = ()          # () => suite default (usually all programs)
     verbose: bool = True
+    interpret: bool = False   # measured tier: Pallas interpreter, not TPU
 
     def resolved(self) -> "BenchConfig":
         """Apply ``quick`` shrinkage — but only to knobs still at their

@@ -102,7 +102,10 @@ def render_markdown(doc: dict) -> str:
         f"refresh. -->",
         "",
         f"Suite: **{doc['suite']}** · generated {created} · "
-        f"jax {env.get('jax')} ({env.get('backend')}) · "
+        f"jax {env.get('jax')} ({env.get('backend')}"
+        f"{', ' + env['device_kind'] if 'device_kind' in env else ''}"
+        f"{' x' + str(env['device_count']) if 'device_count' in env else ''}"
+        f") · "
         f"python {env.get('python')}",
         "",
         f"Config: threads={cfg.get('threads')}, "

@@ -8,7 +8,9 @@ Every suite run produces one *result document*:
   "suite": "paper",
   "created_unix": 1753779600.0,
   "config": {...BenchConfig...},
-  "environment": {"python": "...", "jax": "...", "backend": "cpu"},
+  "environment": {"python": "...", "jax": "...", "backend": "cpu",
+                  "platform": "cpu", "device_kind": "cpu",
+                  "device_count": 1},
   "experiments": [<experiment>, ...]
 }
 ```
@@ -106,15 +108,14 @@ KINDS = ("sweep", "table", "scalars", "hist")
 
 
 def environment_info() -> dict:
-    env = {"python": sys.version.split()[0]}
-    try:
-        import jax
-        env["jax"] = jax.__version__
-        env["backend"] = jax.default_backend()
-    except Exception:  # pragma: no cover - jax is a hard dep in practice
-        env["jax"] = None
-        env["backend"] = None
-    return env
+    """The interpreter, the jax version and the device every number in
+    the document was produced on (``jax.devices()[0]``)."""
+    import jax
+    dev = jax.devices()[0]
+    return {"python": sys.version.split()[0], "jax": jax.__version__,
+            "backend": jax.default_backend(), "platform": dev.platform,
+            "device_kind": dev.device_kind,
+            "device_count": jax.device_count()}
 
 
 def new_result(suite: str, config: dict | None = None,

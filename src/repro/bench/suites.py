@@ -31,7 +31,7 @@ builders end-to-end and is what regenerates ``docs/RESULTS.md``:
                (docs/SERVING.md)
   measured     beyond-sim measured tier (DESIGN.md §L2): the Fig 1-3
                sweeps as real Pallas kernels over the device atomics
-               layer (bench/measured.py; interpret-mode on CPU), the
+               layer (bench/measured.py; --interpret off the TPU), the
                sim-vs-Pallas backend-agreement table, and the CostModel
                calibration error table (bench/calibrate.py)
   kernels      beyond-paper serpentine DMA savings accounting
@@ -1057,8 +1057,9 @@ register("gateway", "Fleet serving gateway (beyond paper, "
 register("measured", "Measured tier: Pallas-backend paper sweeps "
          "(DESIGN.md §L2)",
          "Fig 1-3 style throughput/latency sweeps executed as real "
-         "Pallas kernels over the device atomics layer (interpret-mode "
-         "fallback on CPU), the sim-vs-Pallas backend-agreement table, "
+         "Pallas kernels over the device atomics layer (compiled for the "
+         "TPU; --interpret for the Pallas interpreter), the sim-vs-Pallas "
+         "backend-agreement table, "
          "and the CostModel calibration error table "
          "(bench/calibrate.py).")(build_measured)
 register("kernels", "Serpentine kernel accounting (beyond paper)",

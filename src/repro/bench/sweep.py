@@ -61,7 +61,7 @@ def run_grid(prog, n_threads: int, n_steps: int, seeds, n_nodes,
     slo = _lower_sched_host(None, n_threads)
     return eng._run_batch([int(s) for s in np.asarray(seeds)],
                           [_lower_host(c, n_threads) for c in lows],
-                          [slo] * len(lows), eng.workload, n_threads)
+                          [slo] * len(lows), eng.workload, n_threads)[0]
 
 
 def cached_grid(alg: str, *, seeds, topologies=None, workloads=None,

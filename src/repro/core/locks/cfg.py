@@ -18,9 +18,9 @@ executing every step function once against a recording context
 * records **both** arms of every ``c.when`` instead of jnp-merging them
   (the DSL builds both ``StepOut``s eagerly — data-flow branching — so
   one execution per step surfaces every edge);
-* degrades gracefully when a step body hands symbols to ``jnp.*``
-  (``jnp.where`` on a ``SymVal`` consumes a concrete *witness* value via
-  ``__jax_array__``): the whole extraction runs twice, with thread-id
+* degrades gracefully at value selects: ``c.where`` on symbols selects
+  between their concrete *witness* values with ``jnp.where``, so the
+  result is opaque; the whole extraction runs twice, with thread-id
   witnesses 0 and 1, and joining the two runs re-classifies opaque
   results (an address that shifts by exactly 1 with ``t`` is a
   thread-indexed cell; one that doesn't move is a fixed word).
@@ -85,7 +85,7 @@ class SymVal:
     the provenance roots in ``roots`` ("res", "reg:<name>", "t") with
     ``const`` kept as an additive *base hint* (so ``region.base + f(x)``
     still classifies into the region). ``wit`` is the concrete witness
-    used when jnp consumes the symbol (``__jax_array__``)."""
+    that ``SymCtx.where`` selects on. jnp never sees a ``SymVal``."""
 
     __slots__ = ("const", "tco", "roots", "wit")
 
@@ -188,11 +188,6 @@ class SymVal:
         raise SpecError(
             "step control flow must be data-flow (`c.when(...)`), not a "
             "Python `if` on a traced value")
-
-    # -- jnp degradation ------------------------------------------------------
-    def __jax_array__(self):
-        import jax.numpy as jnp
-        return jnp.asarray(self.wit)
 
     def __repr__(self):
         if not self.roots:
@@ -334,6 +329,13 @@ class SymCtx:
     def enter_cs(self, admit=False, arrive=False):
         return _SymOut(op=None, to=CS, arrive=bool(arrive),
                        admit=bool(admit))
+
+    @staticmethod
+    def where(cond, a, b):
+        """Select on the concrete witnesses: an opaque array result."""
+        import jax.numpy as jnp
+        return jnp.where(*(x.wit if isinstance(x, SymVal) else x
+                           for x in (cond, a, b)))
 
     def when(self, cond, then, other, *, arrive=None, admit=None):
         del cond                            # both arms recorded

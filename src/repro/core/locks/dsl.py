@@ -32,7 +32,7 @@ What the DSL removes relative to hand-rolled handler tables:
 Control flow is data-flow, exactly as in the underlying machine: a step
 branches with ``c.when(cond, then_out, else_out)``, which merges two
 ``StepOut``s component-wise with ``jnp.where``. Conditional register
-updates are written the same way: ``c.r.succ = jnp.where(cond, a, b)``.
+updates are written the same way: ``c.r.succ = c.where(cond, a, b)``.
 
 A complete lock in ~15 lines (see ``core/locks/specs.py`` for the zoo,
 ``examples/define_a_lock.py`` for a runnable walkthrough)::
@@ -202,7 +202,11 @@ class SpecError(ValueError):
 class _Regs:
     """Attribute-style symbolic register file: ``c.r.succ = value`` lowers
     to ``regs.at[i].set(value)``; reads return ``regs[i]``. Conditional
-    updates are data-flow: ``c.r.x = jnp.where(cond, a, b)``."""
+    updates are data-flow: ``c.r.x = c.where(cond, a, b)``.
+
+    The file is an ``(R,)`` array in the sim and a tuple of ``R`` scalars
+    in the Pallas kernel, where a scalar core holds the registers and an
+    indexed array update would lower to a ``scatter`` it cannot run."""
 
     __slots__ = ("_arr", "_map")
 
@@ -224,7 +228,11 @@ class _Regs:
         return self._arr[self._idx(name)]
 
     def __setattr__(self, name, value):
-        arr = self._arr.at[self._idx(name)].set(_i(value))
+        i, arr = self._idx(name), self._arr
+        if isinstance(arr, tuple):
+            arr = arr[:i] + (_i(value),) + arr[i + 1:]
+        else:
+            arr = arr.at[i].set(_i(value))
         object.__setattr__(self, "_arr", arr)
 
 
@@ -273,6 +281,13 @@ class Ctx:
         first ``release`` step."""
         return StepOut(op=self._cs1_op, pc=self._cs2_pc,
                        arrive=arrive, admit=admit)
+
+    @staticmethod
+    def where(cond, a, b):
+        """Data-flow value select (``jnp.where``): how a step picks
+        between two values. The CFG recorder (``core/locks/cfg.py``)
+        replaces it with a select on concrete witnesses."""
+        return jnp.where(cond, a, b)
 
     def when(self, cond, then: StepOut, other: StepOut, *,
              arrive=None, admit=None) -> StepOut:

@@ -19,29 +19,39 @@ uniform cost model). A slice is exactly one turn of the machine's
 op/handler crank:
 
 1. execute the thread's pending op against shared memory via the
-   ``Atomics`` layer (one generic read-modify-write per the
+   ``atomics.rmw`` (one generic read-modify-write per the
    ``ir.OP_TABLE`` contract),
 2. unsatisfied waits (SPIN/PARK) retry next round — no transition;
    timed parks burn a probe budget and complete with ``ok == 0``,
-3. otherwise dispatch the per-pc handler (``lax.switch`` over the IR's
-   handler closures — the same closures the sim runs) and commit the
+3. otherwise dispatch the per-pc handler (a tree of ``lax.cond`` over
+   the IR's handler closures — the same closures the sim runs) and commit the
    transition: registers, next pc, next op, rng.
 
 Lock state, per-thread machine state, and the metrics (episodes,
 admission ring, arrive/admit latency in slices, the mutual-exclusion
-guard/collision counter) all live in aliased output refs, so state
-persists across the whole grid and the kernel is a single device
-launch.
+guard/collision counter) live in SMEM output refs that stay resident
+for the whole grid: the first slice copies the seeded inputs into them,
+every later slice updates them in place, and they are written back once
+at the end. The kernel is a single device launch.
+
+Grid order
+----------
+Both grid axes are declared ``"arbitrary"``. A v5e chip has one
+TensorCore, which runs such a grid's programs one at a time in
+row-major order, so the plain read-modify-writes of
+``core/runtime/atomics.py`` are linearizable on the device exactly as
+they are in the Pallas interpreter. The registers live in scalars (the
+DSL register file is a tuple here), so the kernel emits no ``scatter``.
 
 Modes
 -----
-``interpret=True`` (default on CPU) runs the identical kernel through
-the Pallas interpreter — grid programs execute sequentially, so the
-emulated read-modify-writes are linearizable and CI can run the
-measured tier everywhere. On a real accelerator the atomics layer
-switches to ``pl.atomic_*`` / guard-lock splices. ``backends()``
-probes what this process can actually run (the ``repro.bench list
---backends`` catalogue).
+:func:`run_measured` compiles the kernel for the TPU it runs on and
+raises on any other backend. ``interpret=True`` runs the identical
+kernel through the Pallas interpreter; only callers that ask for it
+(the CPU tests, ``repro.bench run --interpret``) get it.
+:func:`build_measured` returns the jitted call without running it, for
+ahead-of-time compiles and for :func:`backends`, which probes what this
+process can run (the ``repro.bench list --backends`` catalogue).
 """
 from __future__ import annotations
 
@@ -52,17 +62,14 @@ from functools import partial
 import numpy as np
 
 from repro.core.locks.ir import LockIR, lower_spec
-from repro.core.runtime.atomics import PallasAtomics
+from repro.core.runtime.atomics import rmw
 from repro.core.sim import machine as M
 
-__all__ = ["MeasuredResult", "run_measured", "backends", "resolve_ir",
-           "ADM_LOG_M", "GUARD_WORD"]
+__all__ = ["MeasuredResult", "run_measured", "build_measured",
+           "initial_buffers", "backends", "resolve_ir", "ADM_LOG_M"]
 
 #: admission-ring capacity (slot ADM_LOG_M is the overflow spill slot)
 ADM_LOG_M = 256
-#: reserved word for the device-mode atomics guard: every spec's layout
-#: keeps words 6..7 unused (lock words 0..3, CS words 4..5, arrays >= 8)
-GUARD_WORD = 6
 
 
 @dataclass
@@ -72,14 +79,17 @@ class MeasuredResult:
     n_threads: int
     rounds: int
     backend: str                 # "pallas-interpret" | "pallas-device"
+    platform: str                # jax.devices()[0].platform
+    device_kind: str             # jax.devices()[0].device_kind
+    device_count: int            # len(jax.devices())
     episodes: int                # total CS admissions
     per_thread: np.ndarray       # (T,) episodes per thread
     collisions: int              # ME violations observed (must be 0)
     admissions: np.ndarray       # (ADM_LOG_M,) ring of admitted tids
     admission_counts: int        # total admissions (ring position)
     returns: int                 # NCS returns (returns - episodes = aborts)
-    wall_s: float                # wall time of the warm timed launch
-    compile_s: float             # first-launch (trace+compile) time
+    wall_s: float                # host clock around the warm launch
+    compile_s: float             # trace + compile + first launch
 
     @property
     def slices(self) -> int:
@@ -120,28 +130,66 @@ def resolve_ir(lock, n_threads: int, *, ncs_max: int = 0,
 
 # --- kernel -------------------------------------------------------------------
 
-def _build_kernel(ir: LockIR, n_threads: int, atomics: PallasAtomics):
-    """The per-slice kernel body. All state flows through the aliased
-    output refs; the input refs only seed them."""
+#: the state refs, in kernel-argument order (inputs, then the same again
+#: as outputs); ``regs`` and ``cur_op`` are flattened row-major (T*R, T*4)
+STATE = ("mem", "pc", "regs", "cur_op", "rng", "tmo", "episodes",
+         "returns", "arrive_slice", "lat_sum", "held", "scalars", "adm_log")
+
+
+def _copy_ref(src, dst) -> None:
+    """Element-by-element SMEM copy (SMEM is scalar-addressed)."""
+    import jax
+
+    def body(i, c):
+        dst[i] = src[i]
+        return c
+    jax.lax.fori_loop(0, src.shape[0], body, 0)
+
+
+def _switch(idx, branches, *args):
+    """``lax.switch`` as a balanced tree of two-way ``cond``s. Mosaic
+    lowers an N-way switch to an if/else cascade N-1 deep, and its
+    layout pass crashes on the deepest handler tables in the zoo; the
+    tree is log2(N) deep."""
+    import jax
+    if len(branches) == 1:
+        return branches[0](*args)
+    mid = len(branches) // 2
+    return jax.lax.cond(
+        idx < mid,
+        lambda *a: _switch(idx, branches[:mid], *a),
+        lambda *a: _switch(idx - mid, branches[mid:], *a),
+        *args)
+
+
+def _build_kernel(ir: LockIR, n_threads: int):
+    """The per-slice kernel body. The first slice seeds the output refs
+    from the inputs; every slice then updates the outputs in place."""
     import jax
     import jax.numpy as jnp
     from jax.experimental import pallas as pl
 
     T = n_threads
     R = ir.n_regs
+    n = len(STATE)
     handlers = ir.handlers
     i32 = jnp.int32
 
     def kernel(*refs):
-        # inputs [0:13] alias outputs [13:26]; operate on the outputs
-        (mem, pc, regs, cur_op, rng, tmo, episodes, returns,
-         arrive_slice, lat_sum, held, scalars, adm_log) = refs[13:]
         r_idx = pl.program_id(0)
         t = pl.program_id(1).astype(i32)
+
+        @pl.when((r_idx == 0) & (t == 0))
+        def _seed():
+            for src, dst in zip(refs[:n], refs[n:]):
+                _copy_ref(src, dst)
+
+        (mem, pc, regs, cur_op, rng, tmo, episodes, returns,
+         arrive_slice, lat_sum, held, scalars, adm_log) = refs[n:]
         slice_idx = r_idx.astype(i32) * T + t
 
-        kind, addr = cur_op[t, i32(0)], cur_op[t, i32(1)]
-        a, b = cur_op[t, i32(2)], cur_op[t, i32(3)]
+        kind, addr = cur_op[t * 4], cur_op[t * 4 + 1]
+        a, b = cur_op[t * 4 + 2], cur_op[t * 4 + 3]
 
         # -- op classes (ir.OP_TABLE as traced masks) -----------------------
         is_park_to = ((kind == M.PARK_EQ_TIMEOUT)
@@ -151,7 +199,7 @@ def _build_kernel(ir: LockIR, n_threads: int, atomics: PallasAtomics):
         ne_wait = (kind == M.SPIN_NE) | (kind == M.PARK_NE_TIMEOUT)
 
         # -- wait check + timed-park probe budget ---------------------------
-        watched = atomics.load(mem, addr)
+        watched = mem[addr]
         unsat = (eq_wait & (watched != a)) | (ne_wait & (watched == a))
         budget = tmo[t]
         armed = budget >= 0
@@ -165,11 +213,10 @@ def _build_kernel(ir: LockIR, n_threads: int, atomics: PallasAtomics):
                                      jnp.where(armed, budget - 1, b),
                                      budget))
 
-        # -- memory effect: one atomic RMW per the contract table ----------
-        # (waits/loads/delays write the old value back — a no-op by value;
-        # device mode serializes the window through the atomics guard)
+        # -- memory effect: one RMW per the contract table ------------------
+        # (waits/loads/delays write the old value back — a no-op by value)
         eff_kind = jnp.where(do_exec, kind, i32(M.NOP))
-        old = atomics.rmw(mem, addr, eff_kind, a, b)
+        old = rmw(mem, addr, eff_kind, a, b)
 
         # -- result encoding ------------------------------------------------
         cas_ok = (kind == M.CAS) & (old == a)
@@ -180,24 +227,23 @@ def _build_kernel(ir: LockIR, n_threads: int, atomics: PallasAtomics):
 
         # -- DELAY burns real slices-worth of work --------------------------
         iters = jnp.where(do_exec & (kind == M.DELAY), a, 0)
-        burn = jax.lax.fori_loop(0, iters, lambda i, x: x + i, 0)
-        scalars[i32(3)] = scalars[i32(3)] + burn
+        burn = jax.lax.fori_loop(0, iters, lambda i, x: x + i, i32(0))
+        scalars[3] = scalars[3] + burn
 
         # -- transition: dispatch the IR handler at pc ----------------------
         pc_t = pc[t]
-        regs_t = jnp.stack([regs[t, i32(i)] for i in range(R)])
-        outs = jax.lax.switch(pc_t, [partial(h, t) for h in handlers],
-                              regs_t, res, rng[t])
+        regs_t = tuple(regs[t * R + i] for i in range(R))
+        outs = _switch(pc_t, [partial(h, t) for h in handlers],
+                       regs_t, res, rng[t])
         regs_new, next_pc, next_op, arrive, admit, rng_new = outs
 
         pc[t] = jnp.where(do_exec, next_pc, pc_t)
         rng[t] = jnp.where(do_exec, rng_new, rng[t])
         for i in range(R):
-            regs[t, i32(i)] = jnp.where(do_exec, regs_new[i],
-                                        regs[t, i32(i)])
+            regs[t * R + i] = jnp.where(do_exec, regs_new[i], regs_t[i])
         for i in range(4):
-            op_i = jnp.asarray(next_op[i], i32)
-            cur_op[t, i32(i)] = jnp.where(do_exec, op_i, cur_op[t, i32(i)])
+            cur_op[t * 4 + i] = jnp.where(do_exec, jnp.asarray(
+                next_op[i], i32), cur_op[t * 4 + i])
 
         # -- metrics --------------------------------------------------------
         arrive_eff = do_exec & arrive
@@ -212,84 +258,101 @@ def _build_kernel(ir: LockIR, n_threads: int, atomics: PallasAtomics):
 
         # admission ring with a spill slot at ADM_LOG_M: non-admissions
         # and overflow both land in the spill, real entries in 0..K-1
-        cnt = scalars[i32(0)]
+        cnt = scalars[0]
         pos = jnp.where(admit_eff, jnp.minimum(cnt, ADM_LOG_M),
                         i32(ADM_LOG_M))
         adm_log[pos] = jnp.where(admit_eff, t, adm_log[pos])
-        scalars[i32(0)] = cnt + admit_eff.astype(i32)
+        scalars[0] = cnt + admit_eff.astype(i32)
 
         # mutual-exclusion guard: admitted while someone else holds the
         # admit..NCS-return window => collision (must never happen)
-        g = scalars[i32(1)]
-        scalars[i32(2)] = scalars[i32(2)] + jnp.where(
-            admit_eff & (g != 0), 1, 0)
+        g = scalars[1]
+        scalars[2] = scalars[2] + jnp.where(admit_eff & (g != 0), 1, 0)
         dec = (ret & (held[t] != 0)).astype(i32)
-        scalars[i32(1)] = g + admit_eff.astype(i32) - dec
+        scalars[1] = g + admit_eff.astype(i32) - dec
         held[t] = jnp.where(admit_eff, i32(1),
                             jnp.where(ret, i32(0), held[t]))
 
     return kernel
 
 
-def _initial_buffers(ir: LockIR, n_threads: int, seed: int):
-    import jax.numpy as jnp
+def initial_buffers(ir: LockIR, n_threads: int, seed: int) -> tuple:
+    """The seeded state, one host array per :data:`STATE` entry."""
     T, R = n_threads, ir.n_regs
-    mem0 = jnp.zeros(max(ir.n_mem, GUARD_WORD + 1), jnp.int32)
+    mem0 = np.zeros(max(ir.n_mem, 1), np.int32)
     for a, v in ir.init_mem:
-        mem0 = mem0.at[a].set(v)
-    rng0 = (jnp.arange(T, dtype=jnp.uint32) * jnp.uint32(2654435761)
-            + jnp.uint32(seed) * jnp.uint32(97) + jnp.uint32(1))
-    nop = jnp.broadcast_to(jnp.array([M.NOP, 0, 0, 0], jnp.int32), (T, 4))
+        mem0[a] = v
+    rng0 = (np.arange(T, dtype=np.uint32) * np.uint32(2654435761)
+            + np.uint32(seed) * np.uint32(97) + np.uint32(1))
+    nop = np.tile(np.array([M.NOP, 0, 0, 0], np.int32), T)
     return (
         mem0,                                         # mem
-        jnp.zeros(T, jnp.int32),                      # pc
-        jnp.zeros((T, R), jnp.int32),                 # regs
+        np.zeros(T, np.int32),                        # pc
+        np.zeros(T * R, np.int32),                    # regs
         nop,                                          # cur_op
         rng0,                                         # rng
-        jnp.full(T, -1, jnp.int32),                   # tmo
-        jnp.zeros(T, jnp.int32),                      # episodes
-        jnp.zeros(T, jnp.int32),                      # returns
-        jnp.zeros(T, jnp.int32),                      # arrive_slice
-        jnp.zeros(T, jnp.int32),                      # lat_sum
-        jnp.zeros(T, jnp.int32),                      # held
-        jnp.zeros(4, jnp.int32),     # scalars: adm_cnt, guard, coll, burn
-        jnp.full(ADM_LOG_M + 1, -1, jnp.int32),       # adm_log (+spill)
+        np.full(T, -1, np.int32),                     # tmo
+        np.zeros(T, np.int32),                        # episodes
+        np.zeros(T, np.int32),                        # returns
+        np.zeros(T, np.int32),                        # arrive_slice
+        np.zeros(T, np.int32),                        # lat_sum
+        np.zeros(T, np.int32),                        # held
+        np.zeros(4, np.int32),       # scalars: adm_cnt, guard, coll, burn
+        np.full(ADM_LOG_M + 1, -1, np.int32),         # adm_log (+spill)
     )
+
+
+def build_measured(ir: LockIR, n_threads: int, rounds: int, *,
+                   interpret: bool = False):
+    """The jitted ``pallas_call`` of ``ir`` on a ``(rounds, T)`` grid,
+    built and not run: call it on :func:`initial_buffers`, or lower it
+    on their shapes. Every state ref lives in SMEM, and both grid axes
+    are ``"arbitrary"`` so the programs run one at a time, in order."""
+    import jax
+    from jax.experimental import pallas as pl
+    from jax.experimental.pallas import tpu as pltpu
+
+    kernel = _build_kernel(ir, n_threads)
+    shapes = [jax.ShapeDtypeStruct(x.shape, x.dtype)
+              for x in initial_buffers(ir, n_threads, 0)]
+    smem = [pl.BlockSpec(memory_space=pltpu.SMEM)] * len(shapes)
+    return jax.jit(pl.pallas_call(
+        kernel,
+        grid=(rounds, n_threads),
+        in_specs=smem,
+        out_specs=smem,
+        out_shape=shapes,
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("arbitrary", "arbitrary")),
+        interpret=interpret,
+        name=f"lock_{ir.name}",
+    ))
 
 
 def run_measured(lock, n_threads: int, rounds: int, *, ncs_max: int = 0,
                  cs_shared=True, seed: int = 0,
-                 interpret: bool | None = None) -> MeasuredResult:
+                 interpret: bool = False) -> MeasuredResult:
     """Run ``lock`` on the Pallas backend for ``rounds`` round-robin
-    rounds of one micro-op per thread. ``interpret=None`` auto-selects:
-    interpret mode on CPU (the everywhere-runnable fallback), compiled
-    device kernels when an accelerator is present."""
+    rounds of one micro-op per thread: compiled for the TPU this process
+    holds, or through the Pallas interpreter when ``interpret=True``.
+    Raises on a host without a TPU unless ``interpret=True``."""
     import jax
-    import jax.numpy as jnp
-    from jax.experimental import pallas as pl
 
-    if interpret is None:
-        interpret = jax.default_backend() == "cpu"
+    dev = jax.devices()[0]
+    if not interpret and dev.platform != "tpu":
+        raise RuntimeError(
+            f"run_measured compiles for a TPU, and this process sees "
+            f"{dev.platform!r}; pass interpret=True to run the kernel in "
+            "the Pallas interpreter")
     ir = resolve_ir(lock, n_threads, ncs_max=ncs_max, cs_shared=cs_shared)
-    atomics = PallasAtomics(interpret=interpret, guard_idx=GUARD_WORD)
-    kernel = _build_kernel(ir, n_threads, atomics)
-    inits = _initial_buffers(ir, n_threads, seed)
-    out_shape = [jax.ShapeDtypeStruct(x.shape, x.dtype) for x in inits]
-
-    call = pl.pallas_call(
-        kernel,
-        grid=(rounds, n_threads),
-        out_shape=out_shape,
-        input_output_aliases={i: i for i in range(len(inits))},
-        interpret=interpret,
-    )
-    fn = jax.jit(call)
-    t0 = time.time()
+    fn = build_measured(ir, n_threads, rounds, interpret=interpret)
+    inits = [jax.device_put(x) for x in initial_buffers(ir, n_threads, seed)]
+    t0 = time.perf_counter()
     jax.block_until_ready(fn(*inits))          # trace + compile + warm
-    compile_s = time.time() - t0
-    t0 = time.time()
+    compile_s = time.perf_counter() - t0
+    t0 = time.perf_counter()
     outs = jax.block_until_ready(fn(*inits))   # the timed launch
-    wall = time.time() - t0
+    wall = time.perf_counter() - t0
 
     (_mem, _pc, _regs, _op, _rng, _tmo, episodes, returns, _arr, lat_sum,
      _held, scalars, adm_log) = (np.asarray(o) for o in outs)
@@ -298,6 +361,8 @@ def run_measured(lock, n_threads: int, rounds: int, *, ncs_max: int = 0,
     r = MeasuredResult(
         name=ir.name, n_threads=n_threads, rounds=rounds,
         backend="pallas-interpret" if interpret else "pallas-device",
+        platform=dev.platform, device_kind=dev.device_kind,
+        device_count=jax.device_count(),
         episodes=eps, per_thread=episodes, collisions=int(scalars[2]),
         admissions=adm_log[:ADM_LOG_M],
         admission_counts=int(scalars[0]), returns=rets,
@@ -310,27 +375,14 @@ def run_measured(lock, n_threads: int, rounds: int, *, ncs_max: int = 0,
 # --- backend catalogue --------------------------------------------------------
 
 def _probe_pallas(interpret: bool) -> tuple[bool, str]:
-    """Can this process run a minimal aliased-state Pallas kernel (with
-    the atomics layer) in the given mode?"""
+    """Can this process build and run the lock kernel in the given mode?
+    A short ticket-lock run through :func:`run_measured` must admit
+    episodes with no mutual-exclusion collision."""
     try:
-        import jax
-        import jax.numpy as jnp
-        from jax.experimental import pallas as pl
-
-        atomics = PallasAtomics(interpret=interpret, guard_idx=0)
-
-        def k(x_ref, o_ref):
-            old = atomics.fetch_add(o_ref, jnp.int32(1), jnp.int32(2))
-            o_ref[jnp.int32(0)] = old
-
-        out = pl.pallas_call(
-            k, grid=(2,),
-            out_shape=jax.ShapeDtypeStruct((2,), jnp.int32),
-            input_output_aliases={0: 0},
-            interpret=interpret,
-        )(jnp.array([5, 7], jnp.int32))
-        ok = int(np.asarray(out)[1]) == 11
-        return ok, "ok" if ok else f"probe mismatch: {np.asarray(out)}"
+        r = run_measured("ticket", 2, 16, interpret=interpret)
+        ok = r.collisions == 0 and r.episodes > 0
+        return ok, "ok" if ok else (f"probe mismatch: {r.episodes} "
+                                    f"episodes, {r.collisions} collisions")
     except Exception as e:                      # noqa: BLE001
         return False, f"{type(e).__name__}: {e}"[:120]
 
@@ -350,24 +402,22 @@ def backends() -> list:
     rows.append({
         "name": "pallas-interpret",
         "available": ok,
-        "detail": ("Pallas kernel, interpreter mode (CPU fallback; "
-                   "sequential grid => emulated RMWs are linearizable)"
-                   if ok else detail),
+        "detail": ("Pallas kernel in the interpreter (only on request: "
+                   "interpret=True / --interpret)" if ok else detail),
     })
-    plat = jax.default_backend()
-    if plat == "cpu":
+    dev = jax.devices()[0]
+    if dev.platform != "tpu":
         rows.append({
             "name": "pallas-device",
             "available": False,
-            "detail": f"no accelerator (jax backend: {plat})",
+            "detail": f"no TPU (jax platform: {dev.platform})",
         })
     else:
         ok, detail = _probe_pallas(interpret=False)
         rows.append({
             "name": "pallas-device",
             "available": ok,
-            "detail": (f"compiled Pallas kernel on {plat} "
-                       "(pl.atomic_* + guard-lock splices)"
-                       if ok else detail),
+            "detail": (f"compiled Pallas kernel on {dev.device_kind} "
+                       "(SMEM state, grid in order)" if ok else detail),
         })
     return rows

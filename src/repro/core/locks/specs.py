@@ -29,8 +29,6 @@ release chain without entering the CS).
 """
 from __future__ import annotations
 
-import jax.numpy as jnp
-
 from repro.core.locks.dsl import (
     CAS, DELAY, FAA, LOAD, LOCKEDEMPTY, NCS, NOP, PARK_EQ, PARK_EQ_TIMEOUT,
     SPIN_EQ, SPIN_NE, STORE, XCHG,
@@ -61,9 +59,9 @@ def reciprocating(s):
     def consume_tail(c):                    # doorway: inspect the old tail
         E = elem.at(c.t)
         uncont = c.res == 0
-        succ = jnp.where(c.res <= 1, 0, c.res)      # coerce LOCKEDEMPTY
-        c.r.succ = jnp.where(uncont, 0, succ)
-        c.r.eos = jnp.where(uncont, E, 0)
+        succ = c.where(c.res <= 1, 0, c.res)      # coerce LOCKEDEMPTY
+        c.r.succ = c.where(uncont, 0, succ)
+        c.r.eos = c.where(uncont, E, 0)
         return c.when(uncont, c.enter_cs(admit=True),
                       c.op(SPIN_NE(E, 0), to="woke"), arrive=True)
 
@@ -71,8 +69,8 @@ def reciprocating(s):
     def woke(c):                            # res = eos value from the gate
         succ = c.r.succ
         term = succ == c.res                # terminus sentinel?
-        c.r.succ = jnp.where(term, 0, succ)
-        c.r.eos = jnp.where(term, LOCKEDEMPTY, c.res)
+        c.r.succ = c.where(term, 0, succ)
+        c.r.eos = c.where(term, LOCKEDEMPTY, c.res)
         return c.enter_cs(admit=True)
 
     @s.step("release")
@@ -270,7 +268,7 @@ def clh(s):
 
     @s.step("doorway")
     def claim(c):                           # lazy first-episode node init
-        mynode = jnp.where(c.r.mynode == 0, node.at(c.t), c.r.mynode)
+        mynode = c.where(c.r.mynode == 0, node.at(c.t), c.r.mynode)
         c.r.mynode = mynode
         return c.op(STORE(mynode, 1))
 
@@ -722,7 +720,7 @@ def reciprocating_abortable(s):
     @s.step("release")
     def flip(c):
         empty = c.r.tmp == c.r.hi + 1       # no waiters
-        c.r.g = jnp.where(empty, c.r.tmp, c.r.tmp - 1)
+        c.r.g = c.where(empty, c.r.tmp, c.r.tmp - 1)
         return c.when(empty, c.op(STORE(top, c.r.tmp)),
                       c.op(STORE(gr, c.r.tmp - 1), to="publish"))
 

@@ -1,9 +1,8 @@
-"""One ``Atomics`` interface, host and device implementations.
+"""Atomic words for the locks, on the host and inside the lock kernel.
 
 The runtime lock ports (``core/runtime/reciprocating.py``) and the
 measured Pallas backend (``core/locks/pallas_backend.py``) both need
-the same primitive set — load / store / exchange / compare_exchange /
-fetch_add — against very different substrates:
+atomic words, against very different substrates:
 
 * **Host** (:class:`HostAtomics`) — CPython exposes no user-level HW
   atomics, so :class:`AtomicRef` emulates them with a per-ref internal
@@ -11,20 +10,15 @@ fetch_add — against very different substrates:
   structure* of the locks built on top (single-word state, segments,
   zombie end-of-segment, bounded bypass) is exactly the paper's; these
   runtime ports synchronize the framework's data pipeline and
-  checkpoint writer for real.
-* **Device** (:class:`PallasAtomics`) — in-kernel read-modify-writes on
-  a Pallas memory ref. In ``interpret`` mode (the CPU fallback CI runs
-  everywhere) grid programs execute sequentially, so a plain
-  read-modify-write *is* linearizable and the jax interpreter's partial
-  ``pl.atomic_*`` coverage (only ADD/MAX/MIN discharge; XCHG/CAS raise
-  ``NotImplementedError``) never bites. On a real accelerator the same
-  interface lowers to ``pl.atomic_*`` where the primitive exists and to
-  a test-and-set guard-lock splice where it does not (``atomic_cas``
-  only binds scalar refs, so dynamic-index CAS goes through the guard).
-
-Both implementations answer to the same :class:`Atomics` protocol, so a
-lock port is written once against the interface and the substrate is an
-injection site — satellite of the sim->silicon tentpole (ISSUE 10).
+  checkpoint writer for real. A lock port takes an :class:`Atomics`
+  and allocates its cells through ``ref()``.
+* **Device** (:func:`rmw`) — the one generic read-modify-write the
+  machine's op table needs, on a Pallas memory ref. The measured kernel
+  runs on a ``(rounds, T)`` grid whose two axes are declared
+  ``"arbitrary"``: a TPU TensorCore then executes the grid programs one
+  at a time, in row-major order, and so does the interpreter. A plain
+  read-modify-write is therefore linearizable on both, and it is the
+  only body: there is no guard word and no mode switch.
 """
 from __future__ import annotations
 
@@ -66,10 +60,8 @@ class AtomicRef:
 
 
 class Atomics:
-    """The shared interface: allocate cells (host side) or operate on a
-    Pallas ref in-kernel (device side). Implementations provide one of
-    the two surfaces; ``ref()`` is the host allocation entry the runtime
-    lock ports use."""
+    """The interface a runtime lock port is written against: ``ref()``
+    allocates one atomic cell."""
 
     def ref(self, value=None) -> AtomicRef:
         raise NotImplementedError
@@ -90,98 +82,22 @@ def host_atomics() -> HostAtomics:
     return _HOST
 
 
-class PallasAtomics(Atomics):
-    """Device implementation: in-kernel atomics over a Pallas ref.
+def rmw(ref, idx, kind, a, b):
+    """Generic machine-op read-modify-write on a Pallas ref, with traced
+    ``idx``, ``kind`` and values: the effect table of
+    ``core/sim/machine.py`` (STORE/XCHG write ``a``, FAA adds ``a``, CAS
+    writes ``b`` iff ``old == a``, loads/waits leave the word) selected
+    data-flow-style. Returns the old word, mirroring the machine's op
+    results. It is a plain read-modify-write, linearizable because the
+    kernel's grid programs run one at a time (module docstring)."""
+    import jax.numpy as jnp
 
-    Methods take ``(ref, idx, ...)`` with traced ``idx`` and values and
-    return the *old* word, mirroring the machine's op results. With
-    ``interpret=True`` every primitive is a plain read-modify-write —
-    linearizable because interpret mode executes grid programs
-    sequentially. With ``interpret=False`` the maskable primitives use
-    ``pl.atomic_*`` directly and the composite ones (XCHG/CAS at a
-    dynamic index) splice through a per-word exclusive window built on
-    ``pl.atomic_xchg`` over a reserved guard word (index ``guard_idx``
-    in the same ref, conventionally the kernel's dedicated guard slot).
-    """
-
-    def __init__(self, interpret: bool = True, guard_idx: int = 0):
-        self.interpret = interpret
-        self.guard_idx = guard_idx
-
-    # -- exclusive window (device mode only) --------------------------------
-    def _lock_guard(self, ref):
-        import jax
-        from jax.experimental import pallas as pl
-        import jax.numpy as jnp
-        gi = jnp.int32(self.guard_idx)
-
-        def body(_):
-            return pl.atomic_xchg(ref, (gi,), jnp.int32(1))
-        # spin until the exchange returns 0 (we own the window)
-        jax.lax.while_loop(lambda got: got != 0, body,
-                           pl.atomic_xchg(ref, (gi,), jnp.int32(1)))
-
-    def _unlock_guard(self, ref):
-        from jax.experimental import pallas as pl
-        import jax.numpy as jnp
-        pl.atomic_xchg(ref, (jnp.int32(self.guard_idx),), jnp.int32(0))
-
-    # -- primitives ----------------------------------------------------------
-    def load(self, ref, idx):
-        return ref[idx]
-
-    def store(self, ref, idx, value) -> None:
-        ref[idx] = value
-
-    def exchange(self, ref, idx, value):
-        if self.interpret:
-            old = ref[idx]
-            ref[idx] = value
-            return old
-        from jax.experimental import pallas as pl
-        return pl.atomic_xchg(ref, (idx,), value)
-
-    def fetch_add(self, ref, idx, delta):
-        if self.interpret:
-            old = ref[idx]
-            ref[idx] = old + delta
-            return old
-        from jax.experimental import pallas as pl
-        return pl.atomic_add(ref, (idx,), delta)
-
-    def compare_exchange(self, ref, idx, expect, new):
-        """Returns the old value (caller derives ``ok = old == expect``)."""
-        import jax.numpy as jnp
-        if self.interpret:
-            old = ref[idx]
-            ref[idx] = jnp.where(old == expect, new, old)
-            return old
-        # pl.atomic_cas binds only scalar refs — dynamic-index CAS goes
-        # through the guard-lock exclusive window.
-        self._lock_guard(ref)
-        old = ref[idx]
-        ref[idx] = jnp.where(old == expect, new, old)
-        self._unlock_guard(ref)
-        return old
-
-    def rmw(self, ref, idx, kind, a, b):
-        """Generic machine-op read-modify-write with a *traced* kind:
-        the effect table of ``core/sim/machine.py`` (STORE/XCHG write
-        ``a``, FAA adds ``a``, CAS writes ``b`` iff ``old == a``,
-        loads/waits leave the word) selected data-flow-style. Returns
-        the old value. This is the one primitive the measured kernel
-        needs per micro-op."""
-        import jax.numpy as jnp
-        from repro.core.sim import machine as M
-        if not self.interpret:
-            self._lock_guard(ref)
-        old = ref[idx]
-        cas_ok = (kind == M.CAS) & (old == a)
-        newval = jnp.where(kind == M.STORE, a,
-                 jnp.where(kind == M.XCHG, a,
-                 jnp.where(kind == M.FAA, old + a,
-                 jnp.where(cas_ok, b, old))))
-        ref[idx] = newval
-        if not self.interpret:
-            self._unlock_guard(ref)
-        return old
+    from repro.core.sim import machine as M
+    old = ref[idx]
+    cas_ok = (kind == M.CAS) & (old == a)
+    newval = jnp.where(kind == M.STORE, a,
+             jnp.where(kind == M.XCHG, a,
+             jnp.where(kind == M.FAA, old + a,
+             jnp.where(cas_ok, b, old))))
+    ref[idx] = newval
+    return old

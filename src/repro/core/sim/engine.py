@@ -37,8 +37,8 @@ Sharded execution: ``SimEngine(shard=...)`` (or the per-call ``shard=``
 override on ``grid``) routes the vmapped point batch through
 ``shard_map`` over a 1-D device mesh, splitting the stacked
 seed x topology x scheduler axis across devices. ``"auto"`` (the
-default) shards only when >1 device is visible, so single-device hosts
-fall back transparently to the plain vmap path; ``True`` forces the
+default) shards only when >1 device is visible, so a single-device host
+runs the plain vmap path; ``True`` forces the
 shard_map path even on one device (a mesh of 1 — what the differential
 equality tests exercise in-process). Batches are padded to a multiple
 of the shard count by replicating the last point and trimmed after the
@@ -91,41 +91,26 @@ def trace_count() -> int:
 
 # --- sharded execution -------------------------------------------------------
 
-_SHARD_BROKEN = False     # sticky: mesh construction failed once, stay off
-
-
 @functools.lru_cache(maxsize=None)
 def _mesh(n_shards: int):
-    from repro.sharding.compat import make_mesh
+    from repro.sharding.ctx import make_mesh
     return make_mesh((n_shards,), ("cells",))
 
 
-def _resolve_shards(mode, n_points: int) -> int:
-    """Shard count for a batch of ``n_points``: 0 means the plain vmap
-    path; k >= 1 wraps the vmap in ``shard_map`` over a k-device mesh.
-    ``"auto"`` shards only when >1 device is visible; ``True`` forces
-    the shard_map path even on one device; an int asks for that many
-    shards (clamped to the device count)."""
-    global _SHARD_BROKEN
+def _resolve_shards(mode) -> int:
+    """Shard count: 0 means the plain vmap path; k >= 1 wraps the vmap
+    in ``shard_map`` over a k-device mesh. ``"auto"`` shards only when
+    >1 device is visible; ``True`` forces the shard_map path even on one
+    device; an int asks for that many shards (clamped to the device
+    count). A mesh that cannot be built is an error, not a fallback."""
     if mode in (None, False, 0):
         return 0
-    try:
-        n_dev = jax.device_count()
-    except Exception:          # pragma: no cover - jax always has devices
-        return 0
+    n_dev = jax.device_count()
     if mode == "auto":
-        k = n_dev if n_dev > 1 else 0
-    elif mode is True:
-        k = max(n_dev, 1)
-    else:
-        k = max(min(int(mode), n_dev), 1)
-    if k and not _SHARD_BROKEN:
-        try:
-            _mesh(k)
-        except Exception:      # no usable mesh: fall back transparently
-            _SHARD_BROKEN = True
-            k = 0
-    return 0 if _SHARD_BROKEN else k
+        return n_dev if n_dev > 1 else 0
+    if mode is True:
+        return n_dev
+    return max(min(int(mode), n_dev), 1)
 
 
 # --- workloads ---------------------------------------------------------------
@@ -231,9 +216,13 @@ class GridCell:
 class GridResult:
     """Flat cell list (threads-major, then workload, then topology) plus
     the number of fresh XLA traces this grid call paid — 0 when every
-    (threads, workload) shape was already in the session cache."""
+    (threads, workload) shape was already in the session cache — and
+    the number of devices the runner's output was computed on, read
+    from its sharding (0: plain vmap on the default device; a cache
+    replay reports 0 too)."""
     cells: tuple
     compiles: int
+    shards: int = 0
 
     def __iter__(self):
         return iter(self.cells)
@@ -340,11 +329,10 @@ class SimEngine:
                                        LoweredSched(q, lq, co, ji))
                 batched = jax.vmap(one)
                 if n_shards:
-                    from repro.sharding.compat import shard_map
                     spec = jax.sharding.PartitionSpec("cells")
-                    batched = shard_map(batched, mesh=_mesh(n_shards),
-                                        in_specs=spec, out_specs=spec,
-                                        check_vma=False)
+                    batched = jax.shard_map(batched, mesh=_mesh(n_shards),
+                                            in_specs=spec, out_specs=spec,
+                                            check_vma=False)
                 return batched(seeds, hit, miss, remote, park,
                                unpark, resched, quantum, lhp,
                                cores, jitter)
@@ -358,9 +346,10 @@ class SimEngine:
         resolved shard count doesn't divide the batch, the batch is
         padded with copies of its last point and the padding trimmed
         from the result — per-point simulations are independent, so
-        padding never perturbs real points."""
-        k = _resolve_shards(self.shard if shard is None else shard,
-                            len(lowered))
+        padding never perturbs real points. Returns the states and the
+        number of devices the runner's output lives on (0 on the plain
+        vmap path)."""
+        k = _resolve_shards(self.shard if shard is None else shard)
         n = len(lowered)
         seeds, lowered, scheds = list(seeds), list(lowered), list(scheds)
         pad = (-n) % k if k else 0
@@ -374,9 +363,11 @@ class SimEngine:
         sstack = tuple(jnp.asarray(np.stack([sc[i] for sc in scheds]))
                        for i in range(4))
         out = self._runner(T, wl, n + pad, k)(seeds, *stacked, *sstack)
+        used = (len(jax.tree_util.tree_leaves(out)[0].sharding.device_set)
+                if k else 0)
         if pad:
             out = jax.tree_util.tree_map(lambda a: a[:n], out)
-        return out
+        return out, used
 
     # -- execution -----------------------------------------------------------
     def states(self, seeds, *, topology=None, workload=None,
@@ -393,7 +384,7 @@ class SimEngine:
         low = _lower_host(cm, T)
         slo = _lower_sched_host(sc, T)
         return self._run_batch(seeds, [low] * len(seeds),
-                               [slo] * len(seeds), wl, T, shard=shard)
+                               [slo] * len(seeds), wl, T, shard=shard)[0]
 
     def run(self, seed: int = 0, **kw) -> BenchResult:
         """One replica, summarized."""
@@ -431,7 +422,7 @@ class SimEngine:
                          else [self.workload])]
         ts = list(threads) if threads is not None else [self.n_threads]
         c0, S = self.compiles, len(seeds)
-        cells = []
+        cells, shards = [], 0
         for T in ts:
             lows = [(lab, _lower_host(c, T)) for lab, c in topos]
             slos = [(slab, _lower_sched_host(s, T)) for slab, s in schs]
@@ -441,8 +432,8 @@ class SimEngine:
             sbatch = [sl for _, _, _, sl in pairs for _ in range(S)]
             tiled = [s for _ in pairs for s in seeds]
             for wl in wls:
-                st = self._run_batch(tiled, batch, sbatch, wl, T,
-                                     shard=shard)
+                st, shards = self._run_batch(tiled, batch, sbatch, wl, T,
+                                             shard=shard)
                 for p, (lab, _, slab, _) in enumerate(pairs):
                     sl = jax.tree_util.tree_map(
                         lambda a, p=p: a[p * S:(p + 1) * S], st)
@@ -450,7 +441,7 @@ class SimEngine:
                         lock=self.name, n_threads=T, topology=lab,
                         workload=wl.name, scheduler=slab,
                         result=summarize_ensemble(self.name, T, sl)))
-        return GridResult(tuple(cells), self.compiles - c0)
+        return GridResult(tuple(cells), self.compiles - c0, shards)
 
 
 # --- process-wide sessions ---------------------------------------------------

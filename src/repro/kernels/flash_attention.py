@@ -30,10 +30,6 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 import jax.experimental.pallas.tpu as pltpu
 
-# named TPUCompilerParams before the pallas API graduated the prefix
-CompilerParams = getattr(pltpu, "CompilerParams", None) \
-    or pltpu.TPUCompilerParams
-
 F32 = jnp.float32
 NEG_INF = -1e30
 
@@ -144,7 +140,7 @@ def flash_attention_fwd(q, k, v, *, causal=True, window=0,
             pltpu.VMEM((block_q,), F32),
             pltpu.VMEM((block_q, hd), F32),
         ],
-        compiler_params=CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary",
                                  "arbitrary")),
         interpret=interpret,

@@ -20,10 +20,6 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 import jax.experimental.pallas.tpu as pltpu
 
-# named TPUCompilerParams before the pallas API graduated the prefix
-CompilerParams = getattr(pltpu, "CompilerParams", None) \
-    or pltpu.TPUCompilerParams
-
 F32 = jnp.float32
 
 
@@ -94,7 +90,7 @@ def ssd_scan_fwd(x, dt, a_log, bmat, cmat, *, chunk=128, interpret=False):
         out_specs=pl.BlockSpec((1, chunk, H, Pd), lambda b, c: (b, c, 0, 0)),
         out_shape=jax.ShapeDtypeStruct((B, S, H, Pd), x.dtype),
         scratch_shapes=[pltpu.VMEM((H, N, Pd), F32)],
-        compiler_params=CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "arbitrary")),
         interpret=interpret,
     )(a_log, x, dt, bmat, cmat)

@@ -7,8 +7,7 @@ import; smoke tests and benchmarks see the real single device.
 """
 from __future__ import annotations
 
-from repro.sharding.compat import make_mesh
-from repro.sharding.ctx import MeshCtx
+from repro.sharding.ctx import MeshCtx, make_mesh
 
 
 def make_production_mesh(*, multi_pod: bool = False):

@@ -24,7 +24,7 @@ from functools import partial
 
 import jax
 import jax.numpy as jnp
-from repro.sharding.compat import shard_map
+from jax import shard_map
 from jax.sharding import PartitionSpec as P
 
 from repro.sharding.ctx import MeshCtx

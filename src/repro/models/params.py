@@ -19,6 +19,7 @@ Logical axis vocabulary (mapped to mesh axes by ``repro.sharding.rules``):
 """
 from __future__ import annotations
 
+from functools import partial
 from typing import Any, NamedTuple
 
 import jax
@@ -60,23 +61,41 @@ def logical_axes(specs):
     return tree_map_params(lambda p: p.axes, specs)
 
 
-def init_params(specs, key, dtype):
-    """Materialize parameters. Deterministic per-leaf fold of the key."""
-    leaves, treedef = jax.tree.flatten(specs, is_leaf=is_param)
+def _draw_leaf(key, p: Param, dtype):
+    if p.init == "zeros":
+        return jnp.zeros(p.shape, dtype)
+    if p.init == "ones":
+        return jnp.ones(p.shape, dtype)
+    scale = p.scale if p.scale else 1.0 / np.sqrt(max(p.fan_in(), 1))
+    if p.init == "embed":
+        scale = 0.02
+
+    def normal(k, shape):
+        return (jax.random.normal(k, shape, jnp.float32)
+                * float(scale)).astype(dtype)
+    if p.axes[:1] == ("layers",):
+        # a stacked leaf is drawn one layer at a time, under a key per
+        # layer: the TPU compiler's time for one draw grows with its
+        # size, and a loop over layers compiles as one layer does
+        return jax.lax.map(lambda k: normal(k, p.shape[1:]),
+                           jax.random.split(key, p.shape[0]))
+    return normal(key, p.shape)
+
+
+@partial(jax.jit, static_argnums=(1, 2))
+def _draw(key, leaves: tuple, dtype):
+    # one program for the whole tree, compiled once per spec tree; each
+    # leaf's float32 draw fuses with its cast, so a leaf costs its own
+    # bytes in ``dtype`` on the device
     keys = jax.random.split(key, max(len(leaves), 1))
-    out = []
-    for i, p in enumerate(leaves):
-        if p.init == "zeros":
-            out.append(jnp.zeros(p.shape, dtype))
-        elif p.init == "ones":
-            out.append(jnp.ones(p.shape, dtype))
-        else:
-            scale = p.scale if p.scale else 1.0 / np.sqrt(max(p.fan_in(), 1))
-            if p.init == "embed":
-                scale = 0.02
-            out.append((jax.random.normal(keys[i], p.shape, jnp.float32)
-                        * scale).astype(dtype))
-    return jax.tree.unflatten(treedef, out)
+    return [_draw_leaf(keys[i], p, dtype) for i, p in enumerate(leaves)]
+
+
+def init_params(specs, key, dtype):
+    """Materialize parameters on the default device. Deterministic: a
+    key per leaf, split again per layer for stacked leaves."""
+    leaves, treedef = jax.tree.flatten(specs, is_leaf=is_param)
+    return jax.tree.unflatten(treedef, _draw(key, tuple(leaves), dtype))
 
 
 def count_specs(specs) -> int:

@@ -51,8 +51,15 @@ def constrain(x, ctx: "MeshCtx | None", *dims):
     return jax.lax.with_sharding_constraint(x, ctx.sharding(*spec))
 
 
+def make_mesh(shape, axis_names) -> Mesh:
+    """``jax.make_mesh`` with every axis ``Auto``: jax defaults to
+    ``Explicit`` axes, and the model's sharding constraints are written
+    for ``Auto``."""
+    return jax.make_mesh(shape, axis_names, axis_types=(
+        jax.sharding.AxisType.Auto,) * len(axis_names))
+
+
 def trivial_ctx() -> MeshCtx:
     """1x1 mesh on the default device — used by CPU smoke tests."""
-    from repro.sharding.compat import make_mesh
     mesh = make_mesh((1, 1), ("data", "model"))
     return MeshCtx(mesh=mesh, batch_axes=("data",), model_axis="model")

@@ -15,7 +15,7 @@ from __future__ import annotations
 
 import jax
 import jax.numpy as jnp
-from repro.sharding.compat import shard_map
+from jax import shard_map
 from jax.sharding import PartitionSpec as P
 
 F32 = jnp.float32

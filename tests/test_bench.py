@@ -155,7 +155,7 @@ def test_cli_list_backends(capsys):
 
 def test_measured_suite_tiny():
     cfg = BenchConfig(threads=(2, 3), n_steps=250, n_replicas=1,
-                      verbose=False, quick=True,
+                      verbose=False, quick=True, interpret=True,
                       algs=("reciprocating", "ticket"))
     doc = run_suite("measured", cfg)
     assert validate_result(doc) == []
@@ -190,14 +190,14 @@ def test_measured_cells_cache_under_measured_kind():
     store = cachemod.get_cache()
     if not store.enabled:
         pytest.skip("experiment cache disabled")
-    c1 = measured_cell("ticket", 2, 64, seed=11)
+    c1 = measured_cell("ticket", 2, 64, seed=11, interpret=True)
     s0 = store.stats.snapshot()
-    c2 = measured_cell("ticket", 2, 64, seed=11)
+    c2 = measured_cell("ticket", 2, 64, seed=11, interpret=True)
     s1 = store.stats.snapshot()
     assert c2 == c1
     assert s1["hits"] == s0["hits"] + 1
     ir = resolve_ir("ticket", 2)
-    key = _measured_key(ir, 2, 64, 11, True)
+    key = _measured_key(ir, 2, 64, 11, True, c1["device_kind"])
     fp = cachemod.program_fingerprint(ir)
     assert key != cachemod.cell_key(fp, 2, Workload(0, True, 64),
                                     [], [], [11])
@@ -219,7 +219,7 @@ def test_bypass_bounds_match_paper():
 
 
 TINY = BenchConfig(threads=(2,), n_steps=250, n_replicas=1, verbose=False,
-                   quick=True)
+                   quick=True, interpret=True)
 
 
 def test_paper_suite_tiny_sweep():

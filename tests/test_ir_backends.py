@@ -3,26 +3,25 @@
 Four property groups:
 
 * **Golden pinning** — sim output lowered through ``core/locks/ir.py``
-  is bit-identical to the pre-IR one-shot compiler. The digests below
-  were captured from the pre-refactor pipeline (full ``MachineState``,
-  field-declaration order) for every spec in the zoo plus deeper/NUMA
-  settings; any drift in the lowering, the scaffolding injection, or
-  the machine shows up as a digest mismatch.
+  is bit-identical to the pre-IR one-shot compiler. The digests live in
+  ``core/locks/goldens.py`` (full ``MachineState``, field-declaration
+  order) for every spec in the zoo plus deeper/NUMA settings; any drift
+  in the lowering, the scaffolding injection, or the machine shows up as
+  a digest mismatch.
 * **IR surface** — ``lower_spec`` metadata (labels/phases/release pc),
   the ``OP_TABLE`` contract, and the ``compile_spec`` façade.
 * **Backend agreement** — the sim under a uniform cost model dispatches
   exactly the Pallas kernel's round-robin op schedule, so admission
   order and per-thread CS counts must agree across backends.
 * **Pallas semantics** — mutual-exclusion stress (in-kernel guard, zero
-  collisions), and the unified ``Atomics`` protocol host + device.
+  collisions), host atomics and the kernel's read-modify-write.
 """
-import hashlib
-
 import numpy as np
 import pytest
 
 from repro.core.locks import ir as irmod
 from repro.core.locks.compile import compile_spec
+from repro.core.locks.goldens import GOLDEN, run_digest
 from repro.core.locks.ir import OP_TABLE, LockIR, lower_spec, to_sim_program
 from repro.core.locks.programs import PROGRAMS
 from repro.core.locks.specs import SPECS
@@ -31,75 +30,10 @@ from repro.core.sim.machine import CostModel, run_machine
 
 # --- golden pinning -----------------------------------------------------------
 
-# digest = sha256 over every MachineState field (declaration order,
-# name + raw bytes), truncated to 16 hex chars. Captured pre-refactor.
-GOLDEN = {
-    "reciprocating|T=2|ncs=0|cs=True|steps=400|seed=0|default":
-        "e2fc56ee3d17fb6f",
-    "ticket|T=2|ncs=0|cs=True|steps=400|seed=0|default":
-        "b42c869a2ca1cca5",
-    "retrograde|T=2|ncs=0|cs=True|steps=400|seed=0|default":
-        "79960f2ce27e9c2f",
-    "mcs|T=2|ncs=0|cs=True|steps=400|seed=0|default":
-        "8387d5506d68fc6a",
-    "clh|T=2|ncs=0|cs=True|steps=400|seed=0|default":
-        "cae27353224a9dc9",
-    "hemlock|T=2|ncs=0|cs=True|steps=400|seed=0|default":
-        "83eeeeb403745a43",
-    "ttas|T=2|ncs=0|cs=True|steps=400|seed=0|default":
-        "51eefc194c8050d8",
-    "anderson|T=2|ncs=0|cs=True|steps=400|seed=0|default":
-        "0843d215e9932d04",
-    "hapax|T=2|ncs=0|cs=True|steps=400|seed=0|default":
-        "ce0f7386390b478a",
-    "fissile|T=2|ncs=0|cs=True|steps=400|seed=0|default":
-        "287a7bdc2d709441",
-    "spin_then_park|T=2|ncs=0|cs=True|steps=400|seed=0|default":
-        "9210351668cdf6fa",
-    "reciprocating_abortable|T=2|ncs=0|cs=True|steps=400|seed=0|default":
-        "c6802f617dbac80a",
-    "mcs_timeout|T=2|ncs=0|cs=True|steps=400|seed=0|default":
-        "8f001e3d0607a9db",
-    "reciprocating|T=3|ncs=5|cs=ro|steps=500|seed=1|uniform":
-        "39ae02e13b9e5305",
-    "hapax|T=4|ncs=17|cs=True|steps=800|seed=3|default":
-        "54b5eb92cc257a1f",
-    "spin_then_park|T=4|ncs=17|cs=True|steps=800|seed=3|default":
-        "f20fa9e6637b559d",
-    "mcs_timeout|T=3|ncs=5|cs=ro|steps=500|seed=1|uniform":
-        "a7764ebca80d07ef",
-}
-
-_CMS = {"default": CostModel(),
-        "uniform": CostModel(hit=1, local_miss=1, remote_miss=1)}
-
-
-def _digest(state) -> str:
-    h = hashlib.sha256()
-    for f in state._fields:
-        h.update(f.encode())
-        h.update(np.asarray(getattr(state, f)).tobytes())
-    return h.hexdigest()[:16]
-
-
-def _golden_cases():
-    for key, want in GOLDEN.items():
-        name, Ts, ncss, css, stepss, seeds, cm = key.split("|")
-        yield pytest.param(
-            name, int(Ts[2:]), int(ncss[4:]),
-            True if css[3:] == "True" else css[3:],
-            int(stepss[6:]), int(seeds[5:]), cm, want, id=key)
-
-
-@pytest.mark.parametrize(
-    "name,T,ncs,cs,steps,seed,cm,want", list(_golden_cases()))
-def test_sim_through_ir_bit_identical(name, T, ncs, cs, steps, seed, cm,
-                                      want):
-    prog = PROGRAMS[name](T, ncs_max=ncs, cs_shared=cs)
-    s = run_machine(prog, T, steps, cm=_CMS[cm], seed=seed)
-    assert _digest(s) == want, (
-        f"{name}: sim output through the IR drifted from the "
-        "pre-refactor compiler")
+@pytest.mark.parametrize("key", list(GOLDEN))
+def test_sim_through_ir_bit_identical(key):
+    assert run_digest(key) == GOLDEN[key], (
+        f"{key}: sim output through the IR drifted from the pinned digest")
 
 
 def test_golden_covers_every_spec():
@@ -203,12 +137,27 @@ def test_pallas_timed_lock_runs():
     assert r.episodes > 0
 
 
+def test_run_measured_compiles_for_a_tpu_or_raises():
+    """Without ``interpret=True`` the kernel is compiled for the device,
+    and a host without a TPU is an error, never a quiet interpreter
+    run."""
+    import jax
+
+    from repro.core.locks.pallas_backend import run_measured
+
+    if jax.devices()[0].platform == "tpu":
+        pytest.skip("this host has a TPU")
+    with pytest.raises(RuntimeError, match="interpret=True"):
+        run_measured("ticket", 2, 16)
+
+
 def test_measured_result_metrics():
     from repro.core.locks.pallas_backend import run_measured
 
     r = run_measured("ticket", 2, 100, interpret=True)
     assert r.slices == 200
     assert r.backend == "pallas-interpret"
+    assert r.platform == "cpu" and r.device_count >= 1 and r.device_kind
     assert r.throughput_eps > 0 and r.episodes_per_kslice > 0
     assert r.latency_slices >= 0
     assert r.wall_s > 0 and r.compile_s > 0
@@ -226,7 +175,7 @@ def test_backends_catalogue():
         assert isinstance(r["available"], bool) and r["detail"]
 
 
-# --- the unified Atomics protocol --------------------------------------------
+# --- host atomics and the kernel read-modify-write ---------------------------
 
 def test_host_atomics_ref():
     from repro.core.runtime.atomics import AtomicRef, host_atomics
@@ -249,9 +198,8 @@ def test_pallas_atomics_rmw_contract():
     import jax.numpy as jnp
     from jax.experimental import pallas as pl
 
-    from repro.core.runtime.atomics import PallasAtomics
+    from repro.core.runtime.atomics import rmw
 
-    atomics = PallasAtomics(interpret=True)
     ops = jnp.array([
         # (kind, idx, a, b, want_old, want_new)
         [M.LOAD, 0, 0, 0, 10, 10],
@@ -267,7 +215,7 @@ def test_pallas_atomics_rmw_contract():
         i = pl.program_id(0)
         kind, idx = ops_ref[i, jnp.int32(0)], ops_ref[i, jnp.int32(1)]
         a, b = ops_ref[i, jnp.int32(2)], ops_ref[i, jnp.int32(3)]
-        olds[i] = atomics.rmw(mem, idx, kind, a, b)
+        olds[i] = rmw(mem, idx, kind, a, b)
 
     mem0 = jnp.array([10, 20, 30, 40], jnp.int32)
     mem, olds = pl.pallas_call(

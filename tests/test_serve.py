@@ -301,3 +301,35 @@ def test_serve_suite_schema_roundtrip(tmp_path):
         == {s["label"] for s in sweep["series"]}
     md = render_markdown(back)
     assert "Serving" in md and "offered_load" in md
+
+
+# ---------------------------------------------------------------------------
+# serving launcher
+# ---------------------------------------------------------------------------
+def test_launch_serve_shared_prefix_traffic():
+    """The launcher's engine and traffic at smoke widths: every request
+    gets its max_new tokens, and every request after its family's first
+    reuses the family's cached prefix blocks."""
+    from repro.configs import get_config, smoke_config
+    from repro.launch import serve
+
+    cfg = smoke_config(get_config("starcoder2-3b"))
+    eng = serve.build_engine(cfg, seed=0, policy="reciprocating",
+                             max_batch=4, max_seq=128)
+    reqs = serve.shared_prefix_requests(
+        n=6, vocab=cfg.vocab_size, prefix_len=48, suffix_max=16,
+        max_new=8, seed=0)
+    for r in reqs:
+        eng.submit(r)
+    done = eng.run()
+    assert sorted(r.rid for r in done) == list(range(6))
+    for r in reqs:
+        assert 4 <= r.max_new <= 8 and len(r.out) == r.max_new
+        assert r.prefix_id == r.rid % 2 and r.prefix_len == 48
+    assert [r.prefill_hit > 0 for r in reqs] == [False] * 2 + [True] * 4
+
+
+def test_launch_serve_smoke_is_opt_in():
+    from repro.launch.serve import build_parser
+    assert build_parser().parse_args(["--arch", "x"]).smoke is False
+    assert build_parser().parse_args(["--arch", "x", "--smoke"]).smoke

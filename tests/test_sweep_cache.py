@@ -36,6 +36,7 @@ import subprocess
 import sys
 from dataclasses import replace
 
+import jax
 import numpy as np
 import pytest
 
@@ -105,6 +106,7 @@ def test_sharded_grid_bit_identical(lock):
     g0 = eng.grid(**kw, shard=False)
     g1 = eng.grid(**kw, shard=True)
     assert len(g0.cells) == len(g1.cells) == 4
+    assert (g0.shards, g1.shards) == (0, jax.device_count())
     for c0, c1 in zip(g0.cells, g1.cells):
         assert (c0.topology, c0.scheduler) == (c1.topology, c1.scheduler)
         assert_results_identical(
@@ -117,13 +119,14 @@ import json
 import numpy as np
 import jax
 from repro.core.sim.engine import SimEngine, Workload
-checks = []
+checks, shards = [], []
 for lock in ("reciprocating", "mcs"):
     eng = SimEngine(lock, n_threads=4, workload=Workload(0, True, 600))
     # 3 seeds x 2 topologies = 6 points on 4 devices: pads to 8, trims
     kw = dict(seeds=[0, 1, 2], topologies=["smp:4", "numa:2x2"])
     g0 = eng.grid(**kw, shard=False)
     g1 = eng.grid(**kw, shard="auto")
+    shards.append((g0.shards, g1.shards))
     for c0, c1 in zip(g0.cells, g1.cells):
         a, b = c0.result, c1.result
         same = all(getattr(a, f) == getattr(b, f) for f in (
@@ -134,7 +137,7 @@ for lock in ("reciprocating", "mcs"):
         same = same and np.array_equal(a.admission_counts,
                                        b.admission_counts)
         checks.append(bool(same))
-print(json.dumps({"devices": jax.device_count(),
+print(json.dumps({"devices": jax.device_count(), "shards": shards,
                   "n_cells": len(checks), "all_equal": all(checks)}))
 """
 
@@ -152,6 +155,7 @@ def test_sharded_multi_device_bit_identical():
     assert p.returncode == 0, p.stderr[-2000:]
     out = json.loads(p.stdout.strip().splitlines()[-1])
     assert out["devices"] == 4
+    assert out["shards"] == [[0, 4], [0, 4]]   # read from the output
     assert out["n_cells"] == 4
     assert out["all_equal"]
 
