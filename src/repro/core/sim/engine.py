@@ -29,9 +29,18 @@ function), and ``GridResult.compiles`` reports how many a given grid
 call paid. Per-session counters under-count the *process*: code that
 builds a fresh engine per call (``api.bench_lock``, ad-hoc scripts)
 pays traces no session sees, so suite-level accounting (BENCH_trend)
-reads the module-wide ``trace_count()`` instead — it is bumped from the
-same trace-time site as ``self.compiles`` for every engine in the
-process.
+reads the module-wide ``trace_count()`` instead — the ``sim.traces``
+counter of ``repro.tracing``, bumped from the same trace-time site as
+``self.compiles`` for every engine in the process.
+
+Tracing: every ``grid`` call is a ``sim.grid`` span of ``repro.tracing``
+whose children split its host time into phases — ``sim.lower``
+(lowering, stacking and uploading each thread count's batch),
+``sim.dispatch`` (the runner call, with its trace and compile on a cache
+miss, and the first pair's slice of the states, all enqueued),
+``sim.wait`` (the one block on the device's result) and
+``sim.summarize`` (slicing each further pair's states, pulling each
+pair's to the host and summarising it).
 
 Sharded execution: ``SimEngine(shard=...)`` (or the per-call ``shard=``
 override on ``grid``) routes the vmapped point batch through
@@ -61,6 +70,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from repro import tracing
 from repro.core.sim import sched as schedmod
 from repro.core.sim import topology as topo
 from repro.core.sim.api import BenchResult, summarize_ensemble
@@ -74,19 +84,12 @@ __all__ = ["Workload", "WORKLOADS", "SimEngine", "GridCell", "GridResult",
 
 # --- process-wide trace accounting -------------------------------------------
 
-_TRACES = 0
-
-
-def _bump_traces() -> None:
-    global _TRACES
-    _TRACES += 1
-
-
 def trace_count() -> int:
     """Process-wide count of fresh simulator XLA traces, across *every*
-    engine — including throwaway ones no session counter sees. Deltas of
-    this are what ``BENCH_trend.json`` reports per suite run."""
-    return _TRACES
+    engine — including throwaway ones no session counter sees: the
+    ``sim.traces`` counter of ``repro.tracing``. Deltas of this are what
+    ``BENCH_trend.json`` reports per suite run."""
+    return tracing.counter("sim.traces")
 
 
 # --- sharded execution -------------------------------------------------------
@@ -320,7 +323,7 @@ class SimEngine:
             def go(seeds, hit, miss, remote, park, unpark, resched,
                    quantum, lhp, cores, jitter):
                 self.compiles += 1     # runs at trace time only
-                _bump_traces()
+                tracing.count("sim.traces")
 
                 def one(seed, h, m, r, p, u, rs, q, lq, co, ji):
                     return run_machine(prog, T, wl.n_steps,
@@ -339,17 +342,15 @@ class SimEngine:
             fn = self._jits[key] = jax.jit(go)
         return fn
 
-    def _run_batch(self, seeds, lowered, scheds, wl: Workload, T: int,
-                   shard=None):
-        """Elementwise batch: ``seeds[i]`` against ``lowered[i]`` under
-        ``scheds[i]`` (host-lowered scheduler scalar tuples). When the
-        resolved shard count doesn't divide the batch, the batch is
-        padded with copies of its last point and the padding trimmed
-        from the result — per-point simulations are independent, so
-        padding never perturbs real points. Returns the states and the
-        number of devices the runner's output lives on (0 on the plain
-        vmap path)."""
-        k = _resolve_shards(self.shard if shard is None else shard)
+    @staticmethod
+    def _stack(seeds, lowered, scheds, k: int) -> tuple:
+        """The runner's arguments on the device for an elementwise batch:
+        ``seeds[i]`` against ``lowered[i]`` under ``scheds[i]``
+        (host-lowered scheduler scalar tuples). When the shard count
+        ``k`` doesn't divide the batch, the batch is padded with copies
+        of its last point, which ``_launch`` trims from the result —
+        per-point simulations are independent, so padding never perturbs
+        real points."""
         n = len(lowered)
         seeds, lowered, scheds = list(seeds), list(lowered), list(scheds)
         pad = (-n) % k if k else 0
@@ -357,17 +358,31 @@ class SimEngine:
             seeds += [seeds[-1]] * pad
             lowered += [lowered[-1]] * pad
             scheds += [scheds[-1]] * pad
-        seeds = jnp.asarray(seeds, jnp.int32)
-        stacked = tuple(jnp.asarray(np.stack([lo[i] for lo in lowered]))
-                        for i in range(6))
-        sstack = tuple(jnp.asarray(np.stack([sc[i] for sc in scheds]))
-                       for i in range(4))
-        out = self._runner(T, wl, n + pad, k)(seeds, *stacked, *sstack)
+        return (jnp.asarray(seeds, jnp.int32),
+                *(jnp.asarray(np.stack([lo[i] for lo in lowered]))
+                  for i in range(6)),
+                *(jnp.asarray(np.stack([sc[i] for sc in scheds]))
+                  for i in range(4)))
+
+    def _launch(self, args, n: int, wl: Workload, T: int, k: int):
+        """Run ``_stack``'s batch of ``n`` real points (``k`` shards, 0
+        for the plain vmap path). Returns the states, padding trimmed,
+        and the number of devices the runner's output lives on (0 on the
+        plain vmap path)."""
+        out = self._runner(T, wl, len(args[0]), k)(*args)
         used = (len(jax.tree_util.tree_leaves(out)[0].sharding.device_set)
                 if k else 0)
-        if pad:
+        if len(args[0]) > n:
             out = jax.tree_util.tree_map(lambda a: a[:n], out)
         return out, used
+
+    def _run_batch(self, seeds, lowered, scheds, wl: Workload, T: int,
+                   shard=None):
+        """``_stack`` then ``_launch`` under the session's (or the
+        call's) shard mode."""
+        k = _resolve_shards(self.shard if shard is None else shard)
+        return self._launch(self._stack(seeds, lowered, scheds, k),
+                            len(lowered), wl, T, k)
 
     # -- execution -----------------------------------------------------------
     def states(self, seeds, *, topology=None, workload=None,
@@ -423,24 +438,44 @@ class SimEngine:
         ts = list(threads) if threads is not None else [self.n_threads]
         c0, S = self.compiles, len(seeds)
         cells, shards = [], 0
-        for T in ts:
-            lows = [(lab, _lower_host(c, T)) for lab, c in topos]
-            slos = [(slab, _lower_sched_host(s, T)) for slab, s in schs]
-            pairs = [(lab, lo, slab, sl)
-                     for lab, lo in lows for slab, sl in slos]
-            batch = [lo for _, lo, _, _ in pairs for _ in range(S)]
-            sbatch = [sl for _, _, _, sl in pairs for _ in range(S)]
-            tiled = [s for _ in pairs for s in seeds]
-            for wl in wls:
-                st, shards = self._run_batch(tiled, batch, sbatch, wl, T,
-                                             shard=shard)
-                for p, (lab, _, slab, _) in enumerate(pairs):
-                    sl = jax.tree_util.tree_map(
-                        lambda a, p=p: a[p * S:(p + 1) * S], st)
-                    cells.append(GridCell(
-                        lock=self.name, n_threads=T, topology=lab,
-                        workload=wl.name, scheduler=slab,
-                        result=summarize_ensemble(self.name, T, sl)))
+
+        def pair_state(st, p):
+            return jax.tree_util.tree_map(lambda a: a[p * S:(p + 1) * S], st)
+
+        with tracing.span("sim.grid"):
+            k = _resolve_shards(self.shard if shard is None else shard)
+            for T in ts:
+                # the batch is the same data for every workload
+                with tracing.span("sim.lower"):
+                    lows = [(lab, _lower_host(c, T)) for lab, c in topos]
+                    slos = [(slab, _lower_sched_host(s, T))
+                            for slab, s in schs]
+                    pairs = [(lab, lo, slab, sl)
+                             for lab, lo in lows for slab, sl in slos]
+                    args = self._stack(
+                        [s for _ in pairs for s in seeds],
+                        [lo for _, lo, _, _ in pairs for _ in range(S)],
+                        [sl for _, _, _, sl in pairs for _ in range(S)], k)
+                for wl in wls:
+                    # only the first pair's slices are enqueued behind the
+                    # runner before the wait: the TPU runtime keeps at
+                    # most 32 operations in flight and blocks the host on
+                    # the next, so slicing every pair here would hold the
+                    # device wait inside the slicing
+                    with tracing.span("sim.dispatch"):
+                        st, shards = self._launch(args, len(pairs) * S,
+                                                  wl, T, k)
+                        first = pair_state(st, 0)
+                    with tracing.span("sim.wait"):
+                        jax.block_until_ready(first)
+                    with tracing.span("sim.summarize"):
+                        for p, (lab, _, slab, _) in enumerate(pairs):
+                            cells.append(GridCell(
+                                lock=self.name, n_threads=T, topology=lab,
+                                workload=wl.name, scheduler=slab,
+                                result=summarize_ensemble(
+                                    self.name, T,
+                                    first if p == 0 else pair_state(st, p))))
         return GridResult(tuple(cells), self.compiles - c0, shards)
 
 

@@ -23,7 +23,7 @@ import numpy as np
 import pytest
 
 try:
-    from hypothesis import given, settings, strategies as st
+    from hypothesis import example, given, settings, strategies as st
     HAVE_HYPOTHESIS = True
 except ImportError:      # property tests degrade to fixed parametrization
     HAVE_HYPOTHESIS = False
@@ -76,23 +76,28 @@ class OracleTree:
 def _apply_ops(ops):
     """Drive tree + oracle through an op list; cross-check after every
     step. Ops: ('insert', tokens, replica) | ('evict', tokens, depth,
-    replica) | ('match', tokens)."""
+    replica) | ('match', tokens). An eviction names the node the tree
+    last gave the depth-``depth`` prefix of ``tokens`` (a pruned prefix
+    comes back under a new id; its old id is unknown to the tree)."""
     tree = RadixPrefixTree(BT)
     oracle = OracleTree(BT)
-    chains: dict = {}           # tokens-tuple -> chain node ids
+    inserted: set = set()       # tokens-tuples inserted so far
+    ids: dict = {}              # token-prefix tuple -> its latest node id
     for op in ops:
         if op[0] == "insert":
             _, tokens, rep = op
-            chains[tuple(tokens)] = tree.insert(tokens, rep)
+            for k, nid in enumerate(tree.insert(tokens, rep), 1):
+                ids[tuple(tokens[:k * BT])] = nid
+            inserted.add(tuple(tokens))
             oracle.insert(tokens, rep)
         elif op[0] == "evict":
             _, tokens, depth, rep = op
-            chain = chains.get(tuple(tokens), [])
-            if depth <= len(chain):
-                tree.evict(chain[depth - 1], rep)
+            nid = ids.get(tuple(tokens[:depth * BT]))
+            if depth * BT <= len(tokens) and nid is not None:
+                tree.evict(nid, rep)
                 oracle.evict_prefix(tokens, depth, rep)
         tree.check()
-        for probe_tokens in set(chains) | {tuple(op[1])}:
+        for probe_tokens in inserted | {tuple(op[1])}:
             assert tree.match(list(probe_tokens)) == \
                 oracle.match(probe_tokens), (op, probe_tokens)
     return tree
@@ -118,6 +123,8 @@ if HAVE_HYPOTHESIS:
 
     @settings(max_examples=60, deadline=None)
     @given(st.integers(0, 2**32 - 1), st.integers(1, 40))
+    # a prefix pruned, then interned anew by a longer insert, then evicted
+    @example(1_019_738_452, 27)
     def test_tree_matches_oracle(seed, n_ops):
         _apply_ops(_gen_ops(np.random.default_rng(seed), n_ops))
 
