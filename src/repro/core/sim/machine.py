@@ -306,6 +306,26 @@ def _node(t, T, n_nodes):
     return jnp.where(n_nodes <= 1, 0, t // jnp.maximum(T // n_nodes, 1))
 
 
+def _read(hot, x):
+    """``x[i]`` along axis 0, where ``hot`` is the one-hot mask
+    ``arange(len(x)) == i``: a masked reduction, not a gather. Under the
+    engine's vmap ``i`` differs per point, and a gather (or scatter) by
+    per-point data cannot fuse with its neighbours; a select and a sum
+    can. Exact for every dtype; ``i`` must be in range."""
+    m = hot.reshape(hot.shape + (1,) * (x.ndim - hot.ndim))
+    if x.dtype == jnp.bool_:
+        return (m & x).any(tuple(range(hot.ndim)))
+    return jnp.where(m, x, 0).sum(tuple(range(hot.ndim)), dtype=x.dtype)
+
+
+def _write(hot, x, v):
+    """``x`` with ``v`` written where ``hot`` is set (``x.at[i].set(v)``
+    for a one-hot ``hot``), broadcast over ``x``'s trailing axes: a
+    select, not a scatter."""
+    return jnp.where(hot.reshape(hot.shape + (1,) * (x.ndim - hot.ndim)),
+                     jnp.asarray(v).astype(x.dtype), x)
+
+
 def machine_step(s: MachineState, prog: Program, cm, n_threads: int,
                  sched=None):
     """Execute one micro-op for the earliest-ready unblocked thread.
@@ -313,10 +333,17 @@ def machine_step(s: MachineState, prog: Program, cm, n_threads: int,
     ``CostModel``, ``topology.Topology``, or a ``LoweredCost``);
     ``sched`` any scheduler description ``lower_sched`` accepts (``None``
     — the degenerate always-running OS — a ``sched.Scheduler``, or a
-    ``LoweredSched``)."""
+    ``LoweredSched``).
+
+    The thread ``t`` and the word ``addr`` differ per point of a vmapped
+    grid, so every read and write at them is a one-hot select
+    (``_read``/``_write``): the step is elementwise work and small
+    reductions that XLA fuses, and no state array is copied whole for a
+    one-element update."""
     T = n_threads
     lc = lower_cost(cm, T)
     ls = lower_sched(sched, T)
+    threads = jnp.arange(T)
 
     # Runnable mask / dispatch key: a blocked thread is dispatchable only
     # at its abort deadline (INF for plain SPIN/PARK waits); a descheduled
@@ -324,10 +351,15 @@ def machine_step(s: MachineState, prog: Program, cm, n_threads: int,
     # is simply "keyed in the future" — PC frozen, no coherence traffic.
     keyed = jnp.where(s.blocked, s.timeout_at, s.ready_at)
     t = jnp.argmin(keyed).astype(I32)
-    kind, addr, a, b = (s.cur_op[t, 0], s.cur_op[t, 1], s.cur_op[t, 2],
-                        s.cur_op[t, 3])
-    mval = s.mem[addr]
-    start = jnp.maximum(s.time, keyed[t])
+    hot_t = threads == t
+    kind, addr, a, b = _read(hot_t, s.cur_op)
+    hot_a = jnp.arange(prog.n_mem) == addr
+    mval = _read(hot_a, s.mem)
+    own = _read(hot_a, s.owner)           # s.owner[addr]
+    sh_col = (s.sharers & hot_a[None, :]).any(1)   # s.sharers[:, addr]
+    sh_ta = _read(hot_t, sh_col)          # s.sharers[t, addr]
+    start = jnp.maximum(s.time, keyed.min())
+    timeout_t = _read(hot_t, s.timeout_at)
 
     is_park_to = (kind == PARK_EQ_TIMEOUT) | (kind == PARK_NE_TIMEOUT)
     is_park = (kind == PARK_EQ) | is_park_to
@@ -341,24 +373,25 @@ def machine_step(s: MachineState, prog: Program, cm, n_threads: int,
               (kind == PARK_EQ_TIMEOUT)
     ne_wait = (kind == SPIN_NE) | (kind == PARK_NE_TIMEOUT)
     unsat = (eq_wait & (mval != a)) | (ne_wait & (mval == a))
-    timed_out = is_park_to & unsat & (s.timeout_at[t] <= start)
+    timed_out = is_park_to & unsat & (timeout_t <= start)
     spin_unsat = unsat & ~timed_out
 
     # --- cache/cost: distance-in-hierarchy lookup ---------------------------
-    hit = (s.owner[addr] == t) | s.sharers[t, addr]
+    hit = (own == t) | sh_ta
     home_arr = jnp.asarray(prog.home if prog.home else (-1,) * prog.n_mem,
                            I32)
     # home == -1 homes the word with thread 0 (lock/global words, node 0)
-    eff_home = jnp.maximum(home_arr[addr], 0)
-    remote = lc.remote[t, eff_home]
+    eff_home = jnp.maximum(_read(hot_a, home_arr), 0)
+    hot_th = hot_t[:, None] & (threads == eff_home)[None, :]
+    remote = _read(hot_th, lc.remote)     # lc.remote[t, eff_home]
     miss = is_mem & ~hit
     cost = jnp.where(~is_mem, 0,
                      jnp.where(hit & ~is_store, lc.hit,
-                               jnp.where(hit & is_store & (s.owner[addr] == t),
+                               jnp.where(hit & is_store & (own == t),
                                          lc.hit,
-                                         lc.miss[t, eff_home])))
+                                         _read(hot_th, lc.miss))))
     # a store to a merely-Shared line is an upgrade: count as miss-ish
-    upgrade = is_store & s.sharers[t, addr] & (s.owner[addr] != t)
+    upgrade = is_store & sh_ta & (own != t)
     miss = miss | upgrade
 
     # --- memory effect ------------------------------------------------------
@@ -370,42 +403,32 @@ def machine_step(s: MachineState, prog: Program, cm, n_threads: int,
     writes = is_store & ((kind != CAS) | cas_ok)
     # failed CAS still takes the line exclusive (x86 semantics)
     takes_line = is_store
-    res = jnp.where(kind == CAS, mval, jnp.where(is_load, mval, mval))
-    res = jnp.where(kind == XCHG, mval, res)
-    res = jnp.where(kind == FAA, mval, res)
     cas_flag = jnp.where(cas_ok, 1, 0)
 
     do_exec = ~spin_unsat
     eff = do_exec & is_mem
 
-    mem = s.mem.at[addr].set(jnp.where(do_exec & writes, newval, s.mem[addr]))
+    mem = _write(hot_a & do_exec & writes, s.mem, newval)
 
     # coherence updates
-    sh_col = s.sharers[:, addr]
-    others_sharing = sh_col & (jnp.arange(T) != t)
-    n_inval = jnp.where(do_exec & takes_line,
-                        others_sharing.sum() +
-                        ((s.owner[addr] >= 0) & (s.owner[addr] != t)),
-                        0)
+    others_sharing = sh_col & ~hot_t
     inval_recv = s.inval_recv + jnp.where(
         (do_exec & takes_line),
-        others_sharing.astype(I32) +
-        (jnp.arange(T) == s.owner[addr]) * (s.owner[addr] != t), 0)
+        others_sharing.astype(I32) + (threads == own) * (own != t), 0)
 
     # store: invalidate everyone else, become owner
     # load miss: downgrade owner to shared, join sharers
-    new_sh_col = jnp.where(do_exec & takes_line,
-                           jnp.arange(T) == t,
+    # spin's failed probe still caches the line Shared
+    new_sh_col = jnp.where(do_exec & takes_line, hot_t,
                            jnp.where(eff & is_load,
-                                     sh_col | (jnp.arange(T) == t) |
-                                     (jnp.arange(T) == s.owner[addr]),
+                                     sh_col | hot_t | (threads == own),
                                      sh_col))
-    sharers = s.sharers.at[:, addr].set(new_sh_col)
-    owner = s.owner.at[addr].set(
-        jnp.where(do_exec & takes_line, t,
-                  jnp.where(eff & is_load & ~hit, -1, s.owner[addr])))
-    last_writer = s.last_writer.at[addr].set(
-        jnp.where(do_exec & writes, t, s.last_writer[addr]))
+    new_sh_col = new_sh_col | (spin_unsat & hot_t)
+    sharers = jnp.where(hot_a[None, :], new_sh_col[:, None], s.sharers)
+    owner = _write(hot_a, s.owner,
+                   jnp.where(do_exec & takes_line, t,
+                             jnp.where(eff & is_load & ~hit, -1, own)))
+    last_writer = _write(hot_a & do_exec & writes, s.last_writer, t)
 
     # --- timing -------------------------------------------------------------
     # spin first-check also pays its read cost before blocking
@@ -417,23 +440,18 @@ def machine_step(s: MachineState, prog: Program, cm, n_threads: int,
     finish = bus_finish + jnp.where(is_park & spin_unsat, lc.park, 0)
     # bus serializes only on misses (line transfers)
     time = jnp.where(eff & miss | (spin_unsat & ~hit), bus_finish, s.time)
-    ready_at = s.ready_at.at[t].set(finish)
-    misses_ct = s.misses.at[t].add(
-        jnp.where((eff | spin_unsat) & miss, 1, 0))
-    remote_ct = s.remote.at[t].add(
-        jnp.where((eff | spin_unsat) & miss & remote, 1, 0))
-    # spin's failed probe still cached the line Shared
-    sharers = sharers.at[t, addr].set(
-        jnp.where(spin_unsat, True, sharers[t, addr]))
+    ready_at = _write(hot_t, s.ready_at, finish)
+    misses_ct = s.misses + (hot_t & (eff | spin_unsat) & miss)
+    remote_ct = s.remote + (hot_t & (eff | spin_unsat) & miss & remote)
 
     # abortable waiting: arm the deadline on the *first* block of a timed
     # park (spurious wakes keep the original deadline); any completion —
     # satisfied or timed out — disarms it
-    timeout_at = s.timeout_at.at[t].set(
+    timeout_at = _write(
+        hot_t, s.timeout_at,
         jnp.where(do_exec, INF,
-                  jnp.where(spin_unsat & is_park_to
-                            & (s.timeout_at[t] == INF),
-                            finish + b, s.timeout_at[t])))
+                  jnp.where(spin_unsat & is_park_to & (timeout_t == INF),
+                            finish + b, timeout_t)))
 
     # --- wake threads blocked on this word ----------------------------------
     woke = (do_exec & writes) & s.blocked & (s.cur_op[:, 1] == addr)
@@ -443,7 +461,8 @@ def machine_step(s: MachineState, prog: Program, cm, n_threads: int,
     blocked = jnp.where(woke, False, s.blocked)
     # the unpark is a syscall the *waker* executes: its cycles accrue to
     # t's own timeline (one fee per parked sleeper this store wakes)
-    ready_at = ready_at.at[t].add(lc.unpark * (woke & parked).sum())
+    ready_at = ready_at + jnp.where(hot_t, lc.unpark * (woke & parked).sum(),
+                                    0)
     # the sleeper becomes runnable at the waking store's finish; on an
     # oversubscribed machine a woken parker also waits out one re-dispatch
     redisp = jnp.where((ls.cores < T) & parked, lc.resched, 0)
@@ -456,7 +475,7 @@ def machine_step(s: MachineState, prog: Program, cm, n_threads: int,
                            s.slice_used)
     ready_at = jnp.where(woke, jnp.maximum(ready_at, finish) + redisp,
                          ready_at)
-    blocked = blocked.at[t].set(spin_unsat)
+    blocked = _write(hot_t, blocked, spin_unsat)
 
     # --- transition (only when the op completed) -----------------------------
     def run_handler(pc_regs_res):
@@ -469,47 +488,49 @@ def machine_step(s: MachineState, prog: Program, cm, n_threads: int,
     # timed parks pack like CAS: watched * 2 + ok (ok == 0: wait aborted)
     res_in = jnp.where(kind == CAS, mval * 2 + cas_flag,
                        jnp.where(is_park_to,
-                                 mval * 2 + jnp.where(timed_out, 0, 1), res))
+                                 mval * 2 + jnp.where(timed_out, 0, 1), mval))
+    pc_t = _read(hot_t, s.pc)
     regs_t, next_pc, next_op, arrive, admit, rng_t = run_handler(
-        (s.pc[t], s.regs[t], res_in, s.rng[t]))
+        (pc_t, _read(hot_t, s.regs), res_in, _read(hot_t, s.rng)))
 
     adv = do_exec
-    pc = s.pc.at[t].set(jnp.where(adv, next_pc, s.pc[t]))
-    regs = s.regs.at[t].set(jnp.where(adv, regs_t, s.regs[t]))
-    cur_op = s.cur_op.at[t].set(
-        jnp.where(adv, jnp.stack(next_op), s.cur_op[t]))
-    rng = s.rng.at[t].set(jnp.where(adv, rng_t, s.rng[t]))
+    hot_adv = hot_t & adv
+    pc = _write(hot_adv, s.pc, next_pc)
+    regs = _write(hot_adv, s.regs, regs_t)
+    cur_op = _write(hot_adv, s.cur_op, jnp.stack(next_op))
+    rng = _write(hot_adv, s.rng, rng_t)
 
     arrive = adv & arrive
     admit = adv & admit
-    arrive_time = s.arrive_time.at[t].set(
-        jnp.where(arrive, finish, s.arrive_time[t]))
-    lat_sum = s.lat_sum.at[t].add(
-        jnp.where(admit, finish - s.arrive_time[t], 0))
-    episodes = s.episodes.at[t].add(jnp.where(admit, 1, 0))
-    adm_log = s.adm_log.at[s.adm_cnt % ADM_LOG].set(
-        jnp.where(admit, t, s.adm_log[s.adm_cnt % ADM_LOG]))
+    arrive_time = _write(hot_t & arrive, s.arrive_time, finish)
+    lat_sum = s.lat_sum + jnp.where(
+        hot_t & admit, finish - _read(hot_t, s.arrive_time), 0)
+    episodes = s.episodes + (hot_t & admit)
+    adm_log = _write((jnp.arange(ADM_LOG) == s.adm_cnt % ADM_LOG) & admit,
+                     s.adm_log, t)
     adm_cnt = s.adm_cnt + jnp.where(admit, 1, 0)
 
     # every return to the NCS top (admitted or abort path) — so
     # returns - episodes counts aborted acquisitions
-    ret = adv & (next_pc == 0) & (s.pc[t] != 0)
-    returns = s.returns.at[t].add(jnp.where(ret, 1, 0))
+    ret = adv & (next_pc == 0) & (pc_t != 0)
+    returns = s.returns + (hot_t & ret)
     # lock-held window: admission .. NCS return (CS plus release path),
     # the span the lhp_quantum bias tightens
-    holding = jnp.where(admit, True, jnp.where(ret, False, s.in_cs[t]))
-    in_cs = s.in_cs.at[t].set(holding)
+    holding = jnp.where(admit, True,
+                        jnp.where(ret, False, _read(hot_t, s.in_cs)))
+    in_cs = _write(hot_t, s.in_cs, holding)
 
     # --- hostile-OS scheduler: deschedule after the op if over budget -------
     # on-core cycles this dispatch (incl. private park/delay time)
     burn = finish - start
-    slice_new = slice_used[t] + burn
+    slice_new = _read(hot_t, slice_used) + burn
     q_eff = jnp.minimum(jnp.where(holding, ls.lhp_quantum, ls.quantum),
                         ls.quantum)
+    sr = _read(hot_t, s.sched_rng)
     jit_off = jnp.where(
         ls.jitter > 0,
-        (s.sched_rng[t] % (jnp.maximum(ls.jitter, 1).astype(jnp.uint32)
-                           + jnp.uint32(1))).astype(I32), 0)
+        (sr % (jnp.maximum(ls.jitter, 1).astype(jnp.uint32)
+               + jnp.uint32(1))).astype(I32), 0)
     budget = q_eff - jit_off
     preempt = adv & (slice_new >= budget)
     # a preempted thread waits out the other runnables' *base* quanta on
@@ -518,19 +539,17 @@ def machine_step(s: MachineState, prog: Program, cm, n_threads: int,
     # (cores == T), and preempt never fires there (budget == INF)
     gap = ((ls.quantum - jit_off) * (jnp.asarray(T, I32) - ls.cores)
            // jnp.maximum(ls.cores, 1))
-    ready_at = ready_at.at[t].add(
-        jnp.where(preempt, gap + lc.resched, 0))
+    ready_at = ready_at + jnp.where(hot_t & preempt, gap + lc.resched, 0)
     # the slice empties on a deschedule or an off-core park; a spin-block
     # keeps accruing (the busy-wait never yields the core voluntarily)
-    slice_used = slice_used.at[t].set(
+    slice_used = _write(
+        hot_t, slice_used,
         jnp.where(preempt | (spin_unsat & is_park), 0, slice_new))
-    sr = s.sched_rng[t]
     sr = sr ^ (sr << jnp.uint32(13))
     sr = sr ^ (sr >> jnp.uint32(17))
     sr = sr ^ (sr << jnp.uint32(5))
-    sched_rng = s.sched_rng.at[t].set(
-        jnp.where(preempt, sr, s.sched_rng[t]))
-    preempts = s.preempts.at[t].add(jnp.where(preempt, 1, 0))
+    sched_rng = _write(hot_t & preempt, s.sched_rng, sr)
+    preempts = s.preempts + (hot_t & preempt)
 
     return MachineState(mem, owner, sharers, last_writer, pc, regs, cur_op,
                         blocked, ready_at, time, rng, episodes, misses_ct,

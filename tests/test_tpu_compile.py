@@ -80,6 +80,69 @@ def test_sim_grid_runner_compiles(one_chip):
     assert eng.compiles == 1
 
 
+def _scan_body(hlo: str):
+    """Ops of the scan's ``while`` body in compiled HLO text: a counter
+    of the body's own instructions, and the set of instructions inside
+    every computation it calls (fusions, reductions), found by name."""
+    import collections
+    import re
+    comps, cur = {}, None
+    for line in hlo.splitlines():
+        head = re.match(r"^(?:ENTRY )?%?([\w.\-]+) .*\{\s*$", line)
+        if head:
+            cur = comps[head.group(1)] = []
+        elif line.strip() == "}":
+            cur = None
+        elif cur is not None:
+            cur.append(line)
+
+    def ops(lines):
+        return [m.group(1) for m in
+                (re.search(r"= .*?[\])}] ([a-z][\w\-]*)\(", ln)
+                 for ln in lines) if m]
+    bodies = re.findall(r"while\(.*body=%?([\w.\-]+)", hlo)
+    body = max(bodies, key=lambda b: len(comps[b]))
+    inner, todo, seen = set(), [body], {body}
+    while todo:
+        for c in re.findall(r"(?:calls|to_apply)=%?([\w.\-]+)",
+                            "\n".join(comps[todo.pop()])):
+            if c not in seen:
+                seen.add(c)
+                todo.append(c)
+                inner.update(ops(comps[c]))
+    return collections.Counter(ops(comps[body])), inner
+
+
+#: fusions in the scan body of the sim cell's runner per lock, as the
+#: one-hot stepper compiles for a v5e (the indexed stepper had 74-82)
+SCAN_FUSIONS = {"reciprocating": 44, "mcs": 35, "clh": 43, "ticket": 40}
+
+
+@pytest.mark.parametrize("alg", sorted(SCAN_FUSIONS))
+def test_sim_scan_body_selects_not_gathers(one_chip, alg):
+    """The sim cell's runner (T=64, 16 points, NCS 0-249): the stepper
+    reads and writes the chosen thread and word by one-hot selects, so
+    its ``while`` body holds no gather or scatter by per-point data and
+    no transpose of ``sharers``, and its kernel count stays down."""
+    from repro.core.sim.engine import (
+        SimEngine, Workload, _lower_host, _lower_sched_host,
+    )
+    T, n = 64, 16
+    eng = SimEngine(alg, n_threads=T)
+    wl = Workload(250, "rw", 20_000)
+    lows = [_lower_host(t, T) for t in ("epyc-2s", "xeon-4s")] * 8
+    slo = _lower_sched_host(None, T)
+    args = ([np.zeros(n, np.int32)]
+            + [np.stack([lo[i] for lo in lows]) for i in range(6)]
+            + [np.stack([slo[i]] * n) for i in range(4)])
+    hlo = eng._runner(T, wl, n).lower(*_on(one_chip, args)) \
+        .compile().as_text()
+    body, inner = _scan_body(hlo)
+    indexed = {"gather", "scatter", "transpose"} & (set(body) | inner)
+    assert not indexed, indexed
+    assert 0 < body["fusion"] <= SCAN_FUSIONS[alg], body
+
+
 @pytest.fixture(scope="module")
 def starcoder(one_chip):
     """starcoder2-3b at its published widths, cut to 2 layers, with
