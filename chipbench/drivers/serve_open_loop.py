@@ -16,8 +16,14 @@ end of the step that put it on the host; a request's first token is
 timed from when the request was due. After the window closes, the
 engine serves what is left of the requests due in it. A sample of the
 finished requests (drawn from the seed, the longest among them) is then
-compared with the plain reference: the widest gap by which a served
-token's reference logit lies below the reference's best logit.
+compared with the plain reference, teacher-forced over each prompt and
+its served tokens: at every position that put a served token on the
+host, the program's logits of ``LOGIT_IDS`` vocabulary ids drawn from
+the seed against the reference's, as one relative L2 error over the
+sample. The gaps by which served tokens' reference logits lie below the
+reference's best are logged beside it: they read only where a near-tie
+flips, so on a seed whose logits hold no near-tie the program and the
+control both read 0 (PERF.md, section 2).
 
 Spans (``harness.Spans``) wrap the engine's step, the executor's admit,
 prefill and decode calls; counters of their work feed the per-layer
@@ -37,6 +43,8 @@ from chipbench import weights
 from chipbench import work
 from chipbench.reference import dense_lm as ref
 
+#: vocabulary ids at which the program's logits are kept and compared
+LOGIT_IDS = 1024
 #: keys of a dense_lm file the program has no switch for, and the only
 #: values it runs
 FIXED = {"use_bias": False, "norm_type": "rms_norm",
@@ -80,14 +88,24 @@ def build(cfg: dict, seed: int):
     return mcfg, params, eng
 
 
+def logit_ids(vocab: int, seed: int) -> np.ndarray:
+    rng = np.random.default_rng([seed, 31])
+    return np.sort(rng.choice(vocab, min(LOGIT_IDS, vocab), replace=False))
+
+
 class Meter:
     """Wraps the executor's admit, prefill and decode calls in spans, and
     counts their work and times while ``on``. With ``block`` an admit
     ends by waiting for its pool scatters, so that their device time
-    falls inside the admit span rather than the next decode's."""
+    falls inside the admit span rather than the next decode's. Every
+    prefill and decode keeps, per request, the logits of ``ids`` that
+    predicted each of its tokens (``logits``)."""
 
-    def __init__(self, ex, cfg: dict, spans: H.Spans, block: bool):
+    def __init__(self, ex, cfg: dict, spans: H.Spans, block: bool,
+                 ids: np.ndarray):
         self.on = False
+        self.ids = ids
+        self.logits: dict = {}              # rid -> [(len(ids),) ...]
         self.admitted_at: dict = {}         # rid -> host time of admit
         self.c = dict(prefills=0, prefill_tokens=0, prefill_flops=0.0,
                       prefill_bytes=0.0, decodes=0, decode_rows=0,
@@ -110,16 +128,25 @@ class Meter:
         def prefill_(s, n_tokens):
             with spans.span("serve.prefill"):
                 out = prefill(s, n_tokens)
+            self.logits[s.req.rid] = [np.asarray(out)[self.ids]]
             if self.on:
+                # the prefix hit's tokens already have their KV
+                cached = min(round(s.req.prefill_hit * len(s.prompt)),
+                             n_tokens - 1)
                 self.c["prefills"] += 1
                 self.c["prefill_tokens"] += n_tokens
-                self.c["prefill_flops"] += work.prefill_flops(cfg, n_tokens)
+                self.c["prefill_flops"] += work.prefill_flops(
+                    cfg, n_tokens, cached)
                 self.c["prefill_bytes"] += work.prefill_bytes(cfg, n_tokens)
             return out
 
         def decode_(toks, poss):
             with spans.span("serve.decode"):
                 out = decode(toks, poss)
+            kept = np.asarray(out)[:, self.ids]
+            for s in ex.slots:
+                if s is not None:
+                    self.logits[s.req.rid].append(kept[s.idx])
             if self.on:
                 ctxs = [int(poss[s.idx]) for s in ex.slots if s is not None]
                 self.c["decodes"] += 1
@@ -211,7 +238,8 @@ class Server:
         self.s = cfg["serving"]
         self.compiles = H.CompileCounter()
         self.mcfg, self.params, self.eng = build(cfg, seed)
-        self.meter = Meter(self.eng.executor, cfg, spans, block_admits)
+        self.meter = Meter(self.eng.executor, cfg, spans, block_admits,
+                           logit_ids(cfg["vocab_size"], seed))
 
     def reset(self, seed: int) -> None:
         from repro.models import model as M_
@@ -220,9 +248,12 @@ class Server:
         ex = self.eng.executor
         self.params = weights.draw(M_.abstract_params(self.mcfg), seed,
                                    self.mcfg.dtype)
+        self.meter.ids = logit_ids(self.cfg["vocab_size"], seed)
         self.eng.params = ex.params = self.params
         ex.pool = PagedKVPool(self.s["pool_blocks"], reserve_null=True)
         ex.table[:] = 0
+        ex.slots[:] = [None] * len(ex.slots)   # requests the drain left
+        ex._of.clear()
         self.eng.core = ServeCore(ex, policy=self.s["policy"],
                                   max_slots=self.s["max_batch"],
                                   seed=seed % 2**31)
@@ -239,7 +270,8 @@ class Server:
         for r in warm:
             self.eng.submit(to_request(r, self.eng.core.time))
         drain(self.eng, tr["drain_limit_s"])
-        for r in gen.warmup(dict(tr, warmup_requests=2), V, seed + 1):
+        # the same seed: a prompt's prefix id names its tenant's tokens
+        for r in gen.warmup(dict(tr, warmup_requests=2), V, seed):
             r.max_new = 3
             self.eng.submit(to_request(r, self.eng.core.time))
         drain(self.eng, tr["drain_limit_s"])
@@ -257,6 +289,7 @@ class Server:
         meter = self.meter
         meter.c = {k: 0 for k in meter.c}
         meter.admitted_at = {}
+        meter.logits = {}
 
         def submit(r: gen.Req, t_due: float):
             g = to_request(r, eng.core.time)
@@ -311,7 +344,6 @@ class Server:
                                    - (time.perf_counter() - t0)))
             t_close = time.perf_counter()
         self.compiles.counting = meter.on = False
-        reduced = prof.stop() if prof is not None else {}
         if tr["mode"] == "open_loop":   # due in the window, not yet sent
             while i < len(window) and window[i].due_s < seconds:
                 submit(window[i], t0 + window[i].due_s)
@@ -320,6 +352,8 @@ class Server:
         while eng.core.has_work() and (time.perf_counter() - t_drain
                                        < tr["drain_limit_s"]):
             step()
+        # written after the drain, so that no request waits on the write
+        reduced = prof.stop() if prof is not None else {}
         c = dict(meter.c, steps=n_steps, step_wall_s=step_wall,
                  compiles_in_window=self.compiles.count,
                  step_errors=errors)
@@ -356,30 +390,50 @@ def summarize(w: dict) -> dict:
 
 
 def compare(params, cfg: dict, tr: dict, done: list, seed: int,
-            quant: str | None = None) -> dict:
-    """The reference over a sample of the finished requests: the widest
-    gap below the reference's best logit of a served token, and with
-    ``quant`` the control's widest gap at the same positions."""
+            meter: Meter, quant: str | None = None) -> dict:
+    """The reference over a sample of the finished requests: the relative
+    L2 error of the program's kept logits against the reference's, and
+    the widest and mean gap below the reference's best logit of a served
+    token; with ``quant`` the control's at the same positions. A request
+    whose kept logits are not one per served token reads NaN."""
     picked = sample(done, seed, tr["reference_tokens"],
                     tr["reference_requests"])
     # one padded length fits the mix's longest prompt and output
     pad = -(-(tr["block_tokens"] * (tr["shared_blocks"][1]
                                     + tr["unique_blocks"][1])
               + tr["output_cap"]) // 128) * 128
-    worst, worst_ctrl, n = 0.0, 0.0, 0
+    gaps, ctrls = [], []
+    err, ctrl_err, norm = 0.0, 0.0, 0.0
     for g in picked:
-        gap, ctrl = ref.gaps(params, np.asarray(g.tokens),
-                             np.asarray(g.out), cfg, pad, quant)
-        worst = max(worst, float(gap.max()))
-        worst_ctrl = max(worst_ctrl, float(ctrl.max()))
-        n += len(gap)
-    return {"requests": len(picked), "tokens": n, "served_gap": worst,
-            "control_gap": worst_ctrl if quant else None}
+        gap, ctrl, ref_at, low_at = ref.readings(
+            params, np.asarray(g.tokens), np.asarray(g.out), cfg, pad,
+            meter.ids, quant)
+        gaps.append(gap)
+        ctrls.append(ctrl)
+        kept = meter.logits.get(g.rid, [])
+        if len(kept) != len(g.out):
+            err = float("nan")
+        else:
+            err += float(((np.stack(kept) - ref_at) ** 2).sum())
+        ctrl_err += float(((low_at - ref_at) ** 2).sum())
+        norm += float((ref_at ** 2).sum())
+    gap = np.concatenate(gaps) if gaps else np.zeros(0)
+    ctrl = np.concatenate(ctrls) if ctrls else np.zeros(0)
+    out = {"requests": len(picked), "tokens": len(gap),
+           "served_error": (err / norm) ** 0.5 if norm else float("nan"),
+           "served_gap": float(gap.max(initial=0.0)),
+           "served_gap_mean": float(gap.mean()) if len(gap) else 0.0}
+    if quant:
+        out.update(control_error=(ctrl_err / norm) ** 0.5 if norm
+                   else float("nan"),
+                   control_gap=float(ctrl.max(initial=0.0)),
+                   control_gap_mean=float(ctrl.mean()) if len(ctrl) else 0.0)
+    return out
 
 
 def run(ctx: H.RunContext) -> H.RunOutput:
     cfg, tr = ctx.cell.config, ctx.cell.traffic
-    unset = [k for k in ("rate_per_s", "served_logit_gap_limit")
+    unset = [k for k in ("rate_per_s", "served_logit_error_limit")
              if k not in tr and (k != "rate_per_s"
                                  or tr["mode"] == "open_loop")]
     if unset:
@@ -413,21 +467,23 @@ def run(ctx: H.RunContext) -> H.RunOutput:
           f"{len(e['itl_ms'])} gaps; counters {c}")
 
     # the reference, once the engine's state is gone
-    params = srv.params
+    params, meter = srv.params, srv.meter
     done = [g for r, g in w["reqs"].items() if r not in e["unfinished"]]
     del srv
     gc.collect()
     t_ref = time.perf_counter()
-    got = compare(params, cfg, tr, done, ctx.seed)
+    got = compare(params, cfg, tr, done, ctx.seed, meter)
     H.log(f"[check] reference over {got['requests']} requests, "
           f"{got['tokens']} served tokens in "
-          f"{time.perf_counter() - t_ref:.3f} s")
+          f"{time.perf_counter() - t_ref:.3f} s; served-token gap below "
+          f"the reference's best: widest {got['served_gap']!r}, mean "
+          f"{got['served_gap_mean']!r}")
     checks = [
         H.Check("requests_unfinished", len(e["unfinished"]), 0),
         H.Check("tokens_compared", got["tokens"],
                 tr["reference_min_tokens"], at_least=True),
-        H.Check("served_logit_gap", got["served_gap"],
-                tr["served_logit_gap_limit"]),
+        H.Check("served_logit_error", got["served_error"],
+                tr["served_logit_error_limit"]),
     ]
     record = {
         "trace": reduced, "counters": c,
@@ -440,7 +496,8 @@ def run(ctx: H.RunContext) -> H.RunOutput:
     return H.RunOutput(
         checks=checks, attempted=len(w["reqs"]),
         failed=len(e["unfinished"]),
-        end_to_end={"ttft_p95_ms": percentile(e["ttft_ms"], 95),
+        end_to_end={"ttft_p90_ms": percentile(e["ttft_ms"], 90),
+                    "ttft_p95_ms": percentile(e["ttft_ms"], 95),
                     "itl_p95_ms": percentile(e["itl_ms"], 95),
                     "tokens_per_s": e["tokens_in_window"] / e["window_s"],
                     "setup_s": setup_s},

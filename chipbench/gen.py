@@ -7,11 +7,12 @@ prompt prefix of whole blocks; a unique tail of whole blocks per
 request; Poisson bursts, each of one tenant's requests, spread over a
 burst width; lognormal output lengths with a cap.
 
-Every seed sees the same sizes and the same arrival times. They are
-drawn from the file's ``shape_seed``; ``--seed`` then deals the sizes to
-the arrival slots in another order, names the tenants (which prompt
-belongs to which popularity rank) and draws every token. So two seeds
-differ in order and content, never in the amount of work.
+Every seed sees the same sizes and the same arrival times, in the
+window and in the warm-up alike. They are drawn from the file's
+``shape_seed``; ``--seed`` then deals the sizes to the arrival slots in
+another order, names the tenants (which prompt belongs to which
+popularity rank) and draws every token. So two seeds differ in order
+and content, never in the amount of work or the shapes compiled.
 
 ``mode``: ``open_loop`` gives each request the due time of its slot;
 ``saturated`` gives every request due time 0, and the driver keeps its
@@ -129,7 +130,9 @@ def warmup(tr: dict, vocab: int, seed: int) -> list:
     """Set-up's stretch of the same mix from a stream of its own: the
     same tenants, ``warmup_requests`` fresh requests."""
     tenants = Tenants(tr, vocab, seed)
-    rng = np.random.default_rng([seed, 20])
     n = tr["warmup_requests"]
-    sizes = _sizes(tr, rng, n)
-    return _requests(tenants, sizes, np.zeros(n), rng, rid0=10**6)
+    sizes = _sizes(tr, np.random.default_rng([tr["shape_seed"], 3]), n)
+    deal = np.random.default_rng([seed, 21]).permutation(n)
+    sizes = tuple(s[deal] for s in sizes)
+    return _requests(tenants, sizes, np.zeros(n),
+                     np.random.default_rng([seed, 20]), rid0=10**6)

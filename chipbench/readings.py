@@ -11,8 +11,9 @@
 ``control`` reads, for each of ``N`` seeds drawn from the workload's
 name, the numbers a run compares and the control's: for a ``dense_lm``
 cell, a short window at the cell's own load, then over the same sample
-of finished requests the program's widest served-token gap and the
-gap of the reference computed with lower-precision weights; for a
+of finished requests the program's logit error against the reference
+(and its served-token gaps), and the same readings of the reference
+computed with lower-precision weights; for a
 ``lock_sim`` cell, whole runs with the control (the reference with a
 reciprocating lock whose doorway lets a second thread in) in the
 program's place, and the numbers they compare. The benchmark's own runs
@@ -66,9 +67,14 @@ def serve_control(cell, seeds, seconds, quants) -> list:
                "unfinished": len(e["unfinished"]),
                "compiles_in_window": w["counters"]["compiles_in_window"]}
         for q in quants:
-            got = drv.compare(srv.params, cfg, tr, done, seed, q)
-            row.update(tokens=got["tokens"], served_gap=got["served_gap"])
+            got = drv.compare(srv.params, cfg, tr, done, seed, srv.meter, q)
+            row.update(tokens=got["tokens"],
+                       served_error=got["served_error"],
+                       served_gap=got["served_gap"],
+                       served_gap_mean=got["served_gap_mean"])
+            row[f"control_error.{q}"] = got["control_error"]
             row[f"control_gap.{q}"] = got["control_gap"]
+            row[f"control_gap_mean.{q}"] = got["control_gap_mean"]
         print(json.dumps(row), flush=True)
         rows.append(row)
     return rows
@@ -107,13 +113,17 @@ def knee(cell, rates, seconds) -> list:
         if srv is None:
             srv = drv.Server(cfg, t, seed, H.Spans(), block_admits=False)
         srv.tr = t
+        t0 = time.perf_counter()
         srv.set_up(seed, window)
+        setup_s = time.perf_counter() - t0
         w = srv.serve(window, seconds)
         e = drv.summarize(w)
         q = np.asarray(w["queue"], float)
         quarter = max(len(q) // 4, 1)
-        row = {"rate_per_s": rate, "requests": len(w["reqs"]),
+        row = {"rate_per_s": rate, "setup_s": setup_s,
+               "steps": w["counters"]["steps"], "requests": len(w["reqs"]),
                "tokens_per_s": e["tokens_in_window"] / e["window_s"],
+               "ttft_p90_ms": drv.percentile(e["ttft_ms"], 90),
                "ttft_p95_ms": drv.percentile(e["ttft_ms"], 95),
                "itl_p95_ms": drv.percentile(e["itl_ms"], 95),
                "queue_first_quarter": float(q[:quarter].mean()),

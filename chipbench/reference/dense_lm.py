@@ -102,27 +102,28 @@ def _below_best(logits, tokens):
 
 
 @partial(jax.jit, static_argnames=("m", "quant"))
-def _gaps(params, tokens, rows, served, m, quant):
+def _readings(params, tokens, rows, served, ids, m, quant):
     m = dict(m)
     ref = _logits(params, tokens, rows, m, None)
     served_gap = _below_best(ref, served)
     if quant is None:
-        return served_gap, served_gap
+        return served_gap, served_gap, ref[:, ids], ref[:, ids]
     low = _logits(params, tokens, rows, m, quant)
-    return served_gap, _below_best(ref, jnp.argmax(low, -1).astype(
-        jnp.int32))
+    return (served_gap, _below_best(ref, jnp.argmax(low, -1).astype(
+        jnp.int32)), ref[:, ids], low[:, ids])
 
 
-def gaps(params, prompt, served, cfg: dict, pad_to: int,
-         quant: str | None = None):
+def readings(params, prompt, served, cfg: dict, pad_to: int, ids,
+             quant: str | None = None):
     """Teacher-forced over ``prompt`` and the served tokens (the last one
     is never fed back), at every position that predicted a served token:
     the reference's best logit minus its logit of the served token (0
-    when the served token is its own first choice); and, with ``quant``,
-    the control's reading, the reference's best logit minus its logit of
-    the token that the reference computed with ``quant`` weights puts
-    first. The sequence is right-padded to ``pad_to``, which a causal
-    model never reads, so one program serves every request."""
+    when the served token is its own first choice); the same for the
+    token that the control (the reference with ``quant`` weights) puts
+    first; and the reference's and the control's logits of the vocabulary
+    ids ``ids``. Without ``quant`` the control's readings are the
+    reference's. The sequence is right-padded to ``pad_to``, which a
+    causal model never reads, so one program serves every request."""
     seq = np.concatenate([prompt, served[:-1]]).astype(np.int32)
     if len(seq) > pad_to:
         raise ValueError(f"sequence of {len(seq)} over {pad_to}")
@@ -134,7 +135,7 @@ def gaps(params, prompt, served, cfg: dict, pad_to: int,
     want = np.zeros(pad_to, np.int32)
     want[:n] = served
     with jax.default_matmul_precision("highest"):
-        g, c = _gaps(params, jnp.asarray(toks), jnp.asarray(rows),
-                     jnp.asarray(want), tuple(sorted(dims(cfg).items())),
-                     quant)
-    return np.asarray(g)[:n], np.asarray(c)[:n]
+        out = _readings(params, jnp.asarray(toks), jnp.asarray(rows),
+                        jnp.asarray(want), jnp.asarray(ids, jnp.int32),
+                        tuple(sorted(dims(cfg).items())), quant)
+    return tuple(np.asarray(x)[:n] for x in out)

@@ -37,19 +37,24 @@ def kv_bytes_per_token(cfg: dict, dtype_bytes: int = 2) -> int:
     return 2 * m["L"] * m["KV"] * m["hd"] * dtype_bytes
 
 
-def prefill_flops(cfg: dict, n: int) -> float:
-    """FLOPs to prefill ``n`` prompt tokens: every weight matmul for every
-    token, causal attention (token ``i`` attends to ``i + 1``
-    positions) and the one unembedding of the last token."""
+def prefill_flops(cfg: dict, n: int, cached: int = 0) -> float:
+    """Least FLOPs to prefill ``n`` prompt tokens whose first ``cached``
+    already have their KV in the cache (a prefix hit): every weight
+    matmul for each of the other ``n - cached`` tokens, their causal
+    attention (token ``i`` attends to ``i + 1`` positions) and the one
+    unembedding of the last token. A program that computes the cached
+    tokens again does work this does not count."""
     m = dense_dims(cfg)
-    mat = 2 * n * m["L"] * m["layer_weights"]
-    attn = 4 * m["H"] * m["hd"] * m["L"] * n * (n + 1) / 2
+    mat = 2 * (n - cached) * m["L"] * m["layer_weights"]
+    attn = (4 * m["H"] * m["hd"] * m["L"]
+            * (n * (n + 1) - cached * (cached + 1)) / 2)
     return mat + attn + 2 * m["V"] * m["d"]
 
 
 def prefill_bytes(cfg: dict, n: int, dtype_bytes: int = 2) -> float:
     """Least bytes to prefill ``n`` tokens: the layer weights and the
-    unembedding read once, the prompt's KV written once."""
+    unembedding read once, the prompt's KV written once (a cached
+    prefix's KV is read once instead: the same bytes)."""
     m = dense_dims(cfg)
     weights = (m["L"] * m["layer_weights"] + m["V"] * m["d"]) * dtype_bytes
     return weights + n * kv_bytes_per_token(cfg, dtype_bytes)

@@ -10,8 +10,7 @@ from chipbench import gen
 
 FILE = json.loads((Path(__file__).resolve().parents[2] / "chipbench"
                    / "traffic" / "repo_prefix_burst.json").read_text())
-#: the file sets no rate until a knee is read on the chip
-TR = dict(FILE, rate_per_s=9.0)
+TR = FILE
 
 
 def sizes(reqs):
@@ -36,9 +35,11 @@ def test_open_loop_is_seeded_and_in_seconds():
         assert r.tokens.min() >= 1 and r.tokens.max() < 49152
 
 
-def test_seeds_change_order_and_content_not_work():
-    a = gen.schedule(TR, 49152, 1, 20.0)
-    b = gen.schedule(TR, 49152, 2, 20.0)
+@pytest.mark.parametrize("stream", [
+    lambda seed: gen.schedule(TR, 49152, seed, 20.0),
+    lambda seed: gen.warmup(TR, 49152, seed)], ids=["window", "warmup"])
+def test_seeds_change_order_and_content_not_work(stream):
+    a, b = stream(1), stream(2)
     assert [r.due_s for r in a] == [r.due_s for r in b]
     assert sizes(a) == sizes(b)
     assert [r.tokens.tolist() for r in a] != [r.tokens.tolist() for r in b]
@@ -69,6 +70,9 @@ def test_warmup_is_its_own_stream():
 
 
 def test_the_file_has_no_rate_until_the_knee_is_read():
-    assert "rate_per_s" not in FILE
+    # the knee has been read: the rate and the limit are chip readings
+    # (readings.py knee and control), and a mix without a rate is refused
+    assert FILE["rate_per_s"] > 0 and FILE["served_logit_error_limit"] > 0
+    unrated = {k: v for k, v in FILE.items() if k != "rate_per_s"}
     with pytest.raises(ValueError, match="rate_per_s"):
-        gen.schedule(FILE, 49152, 3, 10.0)
+        gen.schedule(unrated, 49152, 3, 10.0)

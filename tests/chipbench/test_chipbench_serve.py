@@ -21,7 +21,7 @@ def small_cell(**traffic):
                         unique_blocks=[1, 2], output_median=4, output_cap=8,
                         warmup_requests=8, reference_tokens=40,
                         reference_requests=12, reference_min_tokens=10,
-                        served_logit_gap_limit=0.05)
+                        served_logit_error_limit=0.02)
     cell.traffic.update(traffic)
     return cell
 
@@ -48,7 +48,7 @@ def test_fp8_control_reads_wider_gaps_than_the_program():
     rows = R.serve_control(cell, R.seeds_for(CELL, 2), 1.5, ["fp8"])
     for row in rows:
         assert row["tokens"] > 0 and row["unfinished"] == 0
-        assert row["control_gap.fp8"] > row["served_gap"]
+        assert row["control_error.fp8"] > row["served_error"]
 
 
 def _kv_unchanged(monkeypatch):
@@ -94,9 +94,30 @@ def test_broken_timed_path_is_not_correct(monkeypatch, fault):
     assert not out.correct
 
 
-@pytest.mark.parametrize("unset", ["rate_per_s", "served_logit_gap_limit"])
+@pytest.mark.parametrize("unset", ["rate_per_s", "served_logit_error_limit"])
 def test_a_traffic_without_chip_readings_is_refused(unset):
     cell = small_cell()
     del cell.traffic[unset]
     with pytest.raises(H.BenchError, match=unset):
         run(cell)
+
+
+def test_reset_drops_the_requests_a_cut_drain_left():
+    from chipbench import gen
+    cell = small_cell(mode="saturated", saturated_requests=24,
+                      output_median=8, output_cap=8)
+    drv, cfg, tr = cell.driver, cell.config, cell.traffic
+    srv = drv.Server(cfg, tr, 5, H.Spans(), block_admits=False)
+    window = gen.schedule(tr, cfg["vocab_size"], 5, 0.5)
+    srv.set_up(5, window)
+    srv.tr = dict(tr, drain_limit_s=0)
+    srv.serve(window, 0.05)
+    ex = srv.eng.executor
+    assert any(s is not None for s in ex.slots)
+    srv.reset(6)
+    srv.tr = tr
+    assert all(s is None for s in ex.slots) and not ex._of
+    window = gen.schedule(tr, cfg["vocab_size"], 6, 0.5)
+    srv.set_up(6, window)
+    w = srv.serve(window, 0.5)
+    assert all(len(g.out) == g.max_new for g in w["reqs"].values())

@@ -87,3 +87,26 @@ def test_reader_finds_nothing_in_an_empty_record(m):
     reader = H.load_module(ROOT / "chipbench" / "readers"
                            / f"{spec['reader']}.py")
     assert reader.read({}, **spec.get("args", {})) is None
+
+
+SERVE_PER_LAYER = {"queue_wait_p95_ms", "prefix_hit_share",
+                   "prefill_step_roofline", "host_ms_per_step",
+                   "compiles_in_window.serve", "decode_step_roofline",
+                   "step_mfu.online", "step_mfu.ttft", "device_idle.online"}
+SIM_PER_LAYER = {"compiles_in_window.sim", "sim.scan_iter_us",
+                 "sim_scan_roofline", "device_idle.sim",
+                 "sim.host_ms_per_call", "sim.lower_ms_per_call",
+                 "sim.summarize_ms_per_call"}
+
+
+@pytest.mark.parametrize("cell,e2e,per_layer", [
+    ("serve.sc2-3b.repo_prefix", {"ttft_p90_ms", "itl_p95_ms", "setup_s"},
+     SERVE_PER_LAYER),
+    ("sim.mutexbench.randncs64", {"lock_steps_per_s", "setup_s"},
+     SIM_PER_LAYER),
+])
+def test_each_cell_reports_its_own_metrics(cell, e2e, per_layer):
+    from chipbench import harness as H
+    found = H.find_cell(cell, ROOT)
+    assert {m["name"] for m in found.end_to_end} == e2e
+    assert {m["name"] for m in found.per_layer} == per_layer

@@ -1,4 +1,6 @@
 """Work counts against hand arithmetic at a small size."""
+import pytest
+
 from chipbench import work
 
 CFG = {"hidden_size": 8, "num_attention_heads": 2, "num_key_value_heads": 1,
@@ -14,10 +16,14 @@ def test_dense_dims():
     assert work.kv_bytes_per_token(CFG) == 2 * 3 * 1 * 4 * 2
 
 
-def test_prefill():
+# with 3 of 5 tokens cached, tokens 3 and 4 attend to 4 and 5 positions
+@pytest.mark.parametrize("cached,attended", [(0, 1 + 2 + 3 + 4 + 5),
+                                             (3, 4 + 5)])
+def test_prefill(cached, attended):
     n = 5
-    attn = 4 * 2 * 4 * 3 * (1 + 2 + 3 + 4 + 5)
-    assert work.prefill_flops(CFG, n) == 2 * n * 3 * LAYER + attn + 2 * 80
+    attn = 4 * 2 * 4 * 3 * attended
+    assert (work.prefill_flops(CFG, n, cached)
+            == 2 * (n - cached) * 3 * LAYER + attn + 2 * 80)
     assert work.prefill_bytes(CFG, n) == (3 * LAYER + 80) * 2 + n * 48
 
 
