@@ -1,5 +1,8 @@
 """Driver of serving traffic (``open_loop`` or ``saturated``) for a
-``dense_lm`` configuration, through the program's ``InferenceEngine``.
+serve kind's configuration, through the program's ``InferenceEngine``.
+What it knows of the architecture comes from the kind
+(``harness.load_kind``: the program's config, the work counts, the pool
+arrays) and the plain reference (``harness.load_reference``).
 
 Set-up draws the weights (``weights.draw``), builds the engine at the
 configuration's serving settings, then warms every shape the run will
@@ -40,44 +43,16 @@ import numpy as np
 from chipbench import gen
 from chipbench import harness as H
 from chipbench import weights
-from chipbench import work
-from chipbench.reference import dense_lm as ref
 
 #: vocabulary ids at which the program's logits are kept and compared
 LOGIT_IDS = 1024
-#: keys of a dense_lm file the program has no switch for, and the only
-#: values it runs
-FIXED = {"use_bias": False, "norm_type": "rms_norm",
-         "hidden_act": "gelu_pytorch_tanh", "torch_dtype": "bfloat16"}
 
 
-def model_config(cfg: dict):
-    """The program's ``ModelConfig`` for the file's architecture, with the
-    file's values: the file is what runs."""
-    from repro.configs import get_config
-    for k, v in FIXED.items():
-        if cfg.get(k) != v:
-            raise H.BenchError(f"{cfg['name']}: {k}={cfg.get(k)!r}; the "
-                               f"program runs only {v!r}")
-    window = cfg.get("sliding_window")
-    if window is not None and window < cfg["serving"]["max_seq"]:
-        raise H.BenchError(f"{cfg['name']}: the program attends to every "
-                           f"position; a {window}-token window would bind")
-    return get_config(cfg["program_arch"]).replace(
-        n_layers=cfg["num_hidden_layers"], d_model=cfg["hidden_size"],
-        n_heads=cfg["num_attention_heads"],
-        n_kv_heads=cfg["num_key_value_heads"], head_dim=cfg["head_dim"],
-        d_ff=cfg["intermediate_size"], vocab_size=cfg["vocab_size"],
-        rope_theta=cfg["rope_theta"], norm_eps=cfg["rms_norm_eps"],
-        tie_embeddings=cfg["tie_word_embeddings"], mlp_type="gelu",
-        sliding_window=0)
-
-
-def build(cfg: dict, seed: int):
+def build(cfg: dict, kind, seed: int):
     """(model config, weights, engine)."""
     from repro.models import model as M_
     from repro.serve.engine import InferenceEngine
-    mcfg = model_config(cfg)
+    mcfg = kind.program_config(cfg)
     params = weights.draw(M_.abstract_params(mcfg), seed, mcfg.dtype)
     s = cfg["serving"]
     eng = InferenceEngine(mcfg, params, policy=s["policy"],
@@ -95,13 +70,14 @@ def logit_ids(vocab: int, seed: int) -> np.ndarray:
 
 class Meter:
     """Wraps the executor's admit, prefill and decode calls in spans, and
-    counts their work and times while ``on``. With ``block`` an admit
-    ends by waiting for its pool scatters, so that their device time
-    falls inside the admit span rather than the next decode's. Every
-    prefill and decode keeps, per request, the logits of ``ids`` that
-    predicted each of its tokens (``logits``)."""
+    counts their work (by the kind's counts) and times while ``on``.
+    With ``block`` an admit ends by waiting for its writes to the kind's
+    ``POOLS``, so that their device time falls inside the admit span
+    rather than the next decode's. Every prefill and decode keeps, per
+    request, the logits of ``ids`` that predicted each of its tokens
+    (``logits``)."""
 
-    def __init__(self, ex, cfg: dict, spans: H.Spans, block: bool,
+    def __init__(self, ex, cfg: dict, kind, spans: H.Spans, block: bool,
                  ids: np.ndarray):
         self.on = False
         self.ids = ids
@@ -119,7 +95,8 @@ class Meter:
                 admit(req, now)
                 if block:
                     import jax
-                    jax.block_until_ready((ex.k_pool, ex.v_pool))
+                    jax.block_until_ready(
+                        [getattr(ex, p) for p in kind.POOLS])
             if self.on:
                 n = len(req.tokens)
                 self.c["prompt_tokens"] += n
@@ -135,9 +112,9 @@ class Meter:
                              n_tokens - 1)
                 self.c["prefills"] += 1
                 self.c["prefill_tokens"] += n_tokens
-                self.c["prefill_flops"] += work.prefill_flops(
+                self.c["prefill_flops"] += kind.prefill_flops(
                     cfg, n_tokens, cached)
-                self.c["prefill_bytes"] += work.prefill_bytes(cfg, n_tokens)
+                self.c["prefill_bytes"] += kind.prefill_bytes(cfg, n_tokens)
             return out
 
         def decode_(toks, poss):
@@ -151,8 +128,8 @@ class Meter:
                 ctxs = [int(poss[s.idx]) for s in ex.slots if s is not None]
                 self.c["decodes"] += 1
                 self.c["decode_rows"] += len(ctxs)
-                self.c["decode_flops"] += work.decode_flops(cfg, ctxs)
-                self.c["decode_bytes"] += work.decode_bytes(cfg, ctxs)
+                self.c["decode_flops"] += kind.decode_flops(cfg, ctxs)
+                self.c["decode_bytes"] += kind.decode_bytes(cfg, ctxs)
             return out
 
         ex.admit, ex._prefill_slot, ex._decode_batch = admit_, prefill_, \
@@ -166,12 +143,13 @@ def to_request(r: gen.Req, core_time: float):
                       arrival=core_time)
 
 
-def warm_shapes(eng, params, reqs, block: int) -> dict:
+def warm_shapes(eng, params, reqs, block: int, pools: tuple) -> dict:
     """Compile (or load from the compile cache) every program the
     requests' admissions use: the prefill of each bucket, and the eager
-    slice and pool scatter of each (prefilled blocks, shared blocks)
-    pair, shared being none or the whole declared prefix. Scatters go to
-    the null block. Returns how many of each were warmed."""
+    slice and scatter into each of the executor's ``pools`` of each
+    (prefilled blocks, shared blocks) pair, shared being none or the
+    whole declared prefix. Scatters go to the null block. Returns how
+    many of each were warmed."""
     import jax
     import jax.numpy as jnp
     ex = eng.executor
@@ -182,16 +160,19 @@ def warm_shapes(eng, params, reqs, block: int) -> dict:
         toks = jnp.zeros((1, nb * block), jnp.int32)
         last = jnp.asarray([nb * block - 1], jnp.int32)
         jax.block_until_ready(ex._prefill(params, toks, last))
-    kb = {nb: jnp.zeros((nb,) + ex.k_pool.shape[1:], ex.k_pool.dtype)
-          for nb in buckets}
+    # one block array per (bucket, block shape, dtype), as pools alike share
+    blocks = {}
     for nb, skip in pairs:
         if skip >= nb:
             continue
-        part = kb[nb][skip:]
         tgt = jnp.asarray(np.zeros(nb - skip, np.int32))
-        ex.k_pool = ex.k_pool.at[tgt].set(part)
-        ex.v_pool = ex.v_pool.at[tgt].set(part)
-    jax.block_until_ready((ex.k_pool, ex.v_pool))
+        for p in pools:
+            pool = getattr(ex, p)
+            key = (nb, pool.shape[1:], pool.dtype)
+            if key not in blocks:
+                blocks[key] = jnp.zeros((nb,) + key[1], key[2])
+            setattr(ex, p, pool.at[tgt].set(blocks[key][skip:]))
+    jax.block_until_ready([getattr(ex, p) for p in pools])
     return {"prefill_buckets": len(buckets), "admit_shapes": len(pairs)}
 
 
@@ -236,10 +217,11 @@ class Server:
                  block_admits: bool):
         self.cfg, self.tr, self.spans = cfg, tr, spans
         self.s = cfg["serving"]
+        self.kind, self.ref = H.load_kind(cfg), H.load_reference(cfg)
         self.compiles = H.CompileCounter()
-        self.mcfg, self.params, self.eng = build(cfg, seed)
-        self.meter = Meter(self.eng.executor, cfg, spans, block_admits,
-                           logit_ids(cfg["vocab_size"], seed))
+        self.mcfg, self.params, self.eng = build(cfg, self.kind, seed)
+        self.meter = Meter(self.eng.executor, cfg, self.kind, spans,
+                           block_admits, logit_ids(cfg["vocab_size"], seed))
 
     def reset(self, seed: int) -> None:
         from repro.models import model as M_
@@ -266,7 +248,7 @@ class Server:
         for r in warm:
             r.max_new = 1
         warmed = warm_shapes(self.eng, self.params, window + warm,
-                             self.s["block_size"])
+                             self.s["block_size"], self.kind.POOLS)
         for r in warm:
             self.eng.submit(to_request(r, self.eng.core.time))
         drain(self.eng, tr["drain_limit_s"])
@@ -389,9 +371,10 @@ def summarize(w: dict) -> dict:
     }
 
 
-def compare(params, cfg: dict, tr: dict, done: list, seed: int,
+def compare(ref, params, cfg: dict, tr: dict, done: list, seed: int,
             meter: Meter, quant: str | None = None) -> dict:
-    """The reference over a sample of the finished requests: the relative
+    """The reference ``ref`` over a sample of the finished requests: the
+    relative
     L2 error of the program's kept logits against the reference's, and
     the widest and mean gap below the reference's best logit of a served
     token; with ``quant`` the control's at the same positions. A request
@@ -467,12 +450,12 @@ def run(ctx: H.RunContext) -> H.RunOutput:
           f"{len(e['itl_ms'])} gaps; counters {c}")
 
     # the reference, once the engine's state is gone
-    params, meter = srv.params, srv.meter
+    ref, params, meter = srv.ref, srv.params, srv.meter
     done = [g for r, g in w["reqs"].items() if r not in e["unfinished"]]
     del srv
     gc.collect()
     t_ref = time.perf_counter()
-    got = compare(params, cfg, tr, done, ctx.seed, meter)
+    got = compare(ref, params, cfg, tr, done, ctx.seed, meter)
     H.log(f"[check] reference over {got['requests']} requests, "
           f"{got['tokens']} served tokens in "
           f"{time.perf_counter() - t_ref:.3f} s; served-token gap below "

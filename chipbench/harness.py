@@ -8,9 +8,26 @@ the file that entry names, its traffic mix is ``traffic/<traffic>.json``
 each per-layer metric is ``metrics/<metric>.json``, which names a reader
 in ``readers/`` and that reader's arguments. A later cell, mix or metric
 is added as files, with no edit here.
+
+A model kind is added as two files, with no edit elsewhere: the
+configuration's ``kind`` key names ``kinds/<kind>.py`` and its
+``reference`` key names ``reference/<reference>.py``. A serve kind
+provides ``program_config(cfg)`` (the program's ``ModelConfig`` from the
+file; a ``BenchError`` for a key the program cannot honour), the
+least-work counts ``prefill_flops(cfg, n, cached)``,
+``prefill_bytes(cfg, n)``, ``decode_flops(cfg, contexts)``,
+``decode_bytes(cfg, contexts)`` and ``token_flops(cfg, context)``, and
+``POOLS``, the names of the executor's pool arrays that an admission
+writes. The counts come from the configuration's sizes and the step's
+contexts alone, never from what the program reports: a routed-expert
+kind counts its expected routed pairs from the configuration, ``top_k x
+held / n_experts`` per token for the ``held`` experts the chip holds. A
+serve reference provides ``readings(params, prompt, served, cfg, pad_to,
+ids, quant)`` (``reference/dense_lm.py``).
 """
 from __future__ import annotations
 
+import functools
 import importlib.util
 import json
 import math
@@ -43,6 +60,33 @@ def load_module(path: Path):
     mod = importlib.util.module_from_spec(spec)
     spec.loader.exec_module(mod)
     return mod
+
+
+#: where a configuration's ``kind`` and ``reference`` keys find their files
+KINDS = HERE / "kinds"
+REFERENCES = HERE / "reference"
+
+
+@functools.cache
+def load_named(folder: Path, name: str, what: str):
+    """``<folder>/<name>.py``, loaded once a process, so that its jitted
+    functions keep their compiled programs from one user to the next."""
+    path = folder / f"{name}.py"
+    if not path.exists():
+        raise BenchError(f"no {what} file {path}: the configuration's "
+                         f"{what!r} key names {name!r}")
+    return load_module(path)
+
+
+def load_kind(cfg: dict):
+    """The serve kind that the configuration's ``kind`` key names."""
+    return load_named(KINDS, cfg["kind"], "kind")
+
+
+def load_reference(cfg: dict):
+    """The plain reference that the configuration's ``reference`` key
+    names."""
+    return load_named(REFERENCES, cfg["reference"], "reference")
 
 
 @dataclass

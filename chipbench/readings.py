@@ -9,11 +9,11 @@
 ``BENCHMARK.json`` yet, by its configuration and traffic files.
 
 ``control`` reads, for each of ``N`` seeds drawn from the workload's
-name, the numbers a run compares and the control's: for a ``dense_lm``
-cell, a short window at the cell's own load, then over the same sample
-of finished requests the program's logit error against the reference
-(and its served-token gaps), and the same readings of the reference
-computed with lower-precision weights; for a
+name, the numbers a run compares and the control's: for a cell of a
+serve kind, a short window at the cell's own load, then over the same
+sample of finished requests the program's logit error against the
+configuration's reference (and its served-token gaps), and the same
+readings of the reference computed with lower-precision weights; for a
 ``lock_sim`` cell, whole runs with the control (the reference with a
 reciprocating lock whose doorway lets a second thread in) in the
 program's place, and the numbers they compare. The benchmark's own runs
@@ -67,7 +67,8 @@ def serve_control(cell, seeds, seconds, quants) -> list:
                "unfinished": len(e["unfinished"]),
                "compiles_in_window": w["counters"]["compiles_in_window"]}
         for q in quants:
-            got = drv.compare(srv.params, cfg, tr, done, seed, srv.meter, q)
+            got = drv.compare(srv.ref, srv.params, cfg, tr, done, seed,
+                              srv.meter, q)
             row.update(tokens=got["tokens"],
                        served_error=got["served_error"],
                        served_gap=got["served_gap"],

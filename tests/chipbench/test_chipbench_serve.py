@@ -121,3 +121,28 @@ def test_reset_drops_the_requests_a_cut_drain_left():
     srv.set_up(6, window)
     w = srv.serve(window, 0.5)
     assert all(len(g.out) == g.max_new for g in w["reqs"].values())
+
+
+def test_a_kind_added_as_two_files_serves_the_cell(tmp_path, monkeypatch):
+    # a kind and a reference that no file of chipbench/ names, found by
+    # the configuration's keys alone
+    for folder, attr in (("kinds", "KINDS"), ("reference", "REFERENCES")):
+        (tmp_path / folder).mkdir()
+        (tmp_path / folder / "dense_lm_copy.py").write_text(
+            (H.HERE / folder / "dense_lm.py").read_text())
+        monkeypatch.setattr(H, attr, tmp_path / folder)
+    cell = small_cell()
+    cell.config.update(kind="dense_lm_copy", reference="dense_lm_copy")
+    out = run(cell)
+    assert out.correct, [(c.name, c.value) for c in out.checks]
+    assert out.record["counters"]["decode_flops"] > 0
+    for load in (H.load_kind, H.load_reference):
+        assert load(cell.config).__file__.startswith(str(tmp_path))
+
+
+@pytest.mark.parametrize("key", ["kind", "reference"])
+def test_a_kind_or_reference_with_no_file_is_refused(key):
+    cell = small_cell()
+    cell.config[key] = "no_such_" + key
+    with pytest.raises(H.BenchError, match=f"no_such_{key}.py"):
+        run(cell)
