@@ -431,17 +431,38 @@ def paged_supported(cfg: ModelConfig, max_seq: int) -> bool:
             and cache_len(cfg, max_seq) == max_seq)
 
 
+#: lanes of a TPU vector register: the paged pools' minor dimension
+LANES = 128
+
+
+def pool_shape(n_blocks: int, L: int, block: int, KV: int, hd: int) -> tuple:
+    """Shape of ``n_blocks`` paged KV blocks: (n_blocks, L, R, LANES) with
+    R = block*KV*hd/LANES. A block's values keep their (block, KV, hd)
+    order, flattened into lane-wide rows, so writing a whole block by id
+    is a dense row update in the layout the pool is stored in; a
+    (block, KV, hd) minor shape is tiled differently from what the
+    scatter wants, and the compiler copies the whole pool around it
+    (SERVING.md §3)."""
+    width = block * KV * hd
+    if width % LANES:
+        raise ValueError(f"a paged KV block of {block} x {KV} x {hd} "
+                         f"values is not a multiple of {LANES} lanes")
+    return (n_blocks, L, width // LANES, LANES)
+
+
 def cache_to_blocks(cache: dict, block: int):
     """Chop a B=1 prefill cache into KV blocks: (L,1,S,KV,hd) k/v ->
-    (S//block, L, block, KV, hd), ready to scatter into the pools by
-    block id. S must be a block multiple (right-pad the prompt)."""
+    ``pool_shape(S//block, L, block, KV, hd)``, ready to scatter into the
+    pools by block id. S must be a block multiple (right-pad the
+    prompt)."""
     k, v = cache["k"], cache["v"]
     L, _, S, KV, hd = k.shape
     assert S % block == 0, (S, block)
+    shape = pool_shape(S // block, L, block, KV, hd)
 
     def chop(t):
-        t = t[:, 0].reshape(L, S // block, block, KV, hd)
-        return t.transpose(1, 0, 2, 3, 4)
+        t = t[:, 0].reshape(L, S // block, block * KV * hd)
+        return t.transpose(1, 0, 2).reshape(shape)
     return chop(k), chop(v)
 
 
@@ -449,26 +470,31 @@ def paged_decode_step(params, k_pool, v_pool, table, pos, tokens,
                       cfg: ModelConfig, ctx: MeshCtx):
     """One decode token per sequence against paged KV pools.
 
-    k_pool/v_pool: (P, L, block, KV, hd) — the pool, indexed by block id.
+    k_pool/v_pool: ``pool_shape(P, L, block, KV, hd)`` — the pool, indexed
+                   by block id.
     table:         (B, nb) int32 — per-slot block tables (id 0 = the null
                    block for unused entries / empty slots).
     pos:           (B,) int32 absolute position of the incoming token.
     tokens:        (B,) int32.
 
     Semantics are identical to ``decode_step`` on the dense cache the
-    table describes: the pools are gathered to (L, B, nb*block, KV, hd),
-    ``slot_pos`` is reconstructed from ``pos`` (slot i holds position i —
-    no ring, enforced by ``paged_supported``), and after the step only
-    the block containing the written slot is scattered back. Empty slots
-    point at the null block, which absorbs their garbage writes.
+    table describes: the pools are gathered by table and unflattened to
+    (L, B, nb*block, KV, hd), ``slot_pos`` is reconstructed from ``pos``
+    (slot i holds position i — no ring, enforced by ``paged_supported``),
+    and after the step only the block containing the written slot is
+    flattened to (L, R, LANES) and scattered back. Empty slots point at
+    the null block, which absorbs their garbage writes.
     Returns (logits, k_pool, v_pool).
     """
     B, nb = table.shape
-    P, L, block, KV, hd = k_pool.shape
+    P, L, R, _ = k_pool.shape
+    KV, hd = cfg.n_kv_heads, cfg.hd
+    block = R * LANES // (KV * hd)
+    assert k_pool.shape == pool_shape(P, L, block, KV, hd), k_pool.shape
     Sc = nb * block
 
     def gather(pool):
-        t = pool[table]                          # (B, nb, L, block, KV, hd)
+        t = pool[table].reshape(B, nb, L, block, KV, hd)
         return t.transpose(2, 0, 1, 3, 4, 5).reshape(L, B, Sc, KV, hd)
 
     iota = jnp.arange(Sc, dtype=jnp.int32)
@@ -480,7 +506,8 @@ def paged_decode_step(params, k_pool, v_pool, table, pos, tokens,
     bi = pos // block                            # block just written, per row
 
     def cut(row, b):                             # row: (L, Sc, KV, hd)
-        return jax.lax.dynamic_slice_in_dim(row, b * block, block, axis=1)
+        t = jax.lax.dynamic_slice_in_dim(row, b * block, block, axis=1)
+        return t.reshape(L, R, LANES)
     ids = table[jnp.arange(B), bi]
     k_pool = k_pool.at[ids].set(jax.vmap(cut, in_axes=(1, 0))(cache["k"], bi))
     v_pool = v_pool.at[ids].set(jax.vmap(cut, in_axes=(1, 0))(cache["v"], bi))

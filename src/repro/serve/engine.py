@@ -185,10 +185,11 @@ class _ModelExecutor(Executor):
 class PagedModelExecutor(_ModelExecutor):
     """Block-table paged KV executor (SERVING.md §3).
 
-    Pools are (P, L, block, KV, hd) device arrays indexed by block id;
-    the host-side ``PagedKVPool`` owns allocation, pinning and the
-    prefix-cache LRU. Full prompt-prefix blocks are shared copy-free
-    between same-``prefix_id`` requests and retained (LRU) after release.
+    Pools are ``decode.pool_shape(P, L, block, KV, hd)`` device arrays,
+    (P, L, block*KV*hd/128, 128), indexed by block id; the host-side
+    ``PagedKVPool`` owns allocation, pinning and the prefix-cache LRU.
+    Full prompt-prefix blocks are shared copy-free between
+    same-``prefix_id`` requests and retained (LRU) after release.
     """
 
     def __init__(self, cfg, params, ctx, max_batch, max_seq, prefill_chunk,
@@ -199,8 +200,8 @@ class PagedModelExecutor(_ModelExecutor):
         self.block = block_size
         self.nb = max_seq // block_size
         self.pool = PagedKVPool(pool_blocks, reserve_null=True)
-        L, KV, hd = cfg.n_layers, cfg.n_kv_heads, cfg.hd
-        shape = (pool_blocks, L, block_size, KV, hd)
+        shape = D_.pool_shape(pool_blocks, cfg.n_layers, block_size,
+                              cfg.n_kv_heads, cfg.hd)
         self.k_pool = jnp.zeros(shape, cfg.dtype)
         self.v_pool = jnp.zeros(shape, cfg.dtype)
         self.table = np.zeros((max_batch, self.nb), np.int32)

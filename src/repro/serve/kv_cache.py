@@ -20,7 +20,10 @@ The same class serves two clients with two views of the same bookkeeping:
 * the **model engine** (`serve/engine.py`) uses the id-level API
   (``alloc`` / ``share`` / ``release``) and keeps the actual device
   arrays, indexed by block id, next to the jitted decode step
-  (``models/decode.py::paged_decode_step`` gathers by block table);
+  (``models/decode.py::paged_decode_step`` gathers by block table). Each
+  array is shaped ``models/decode.py::pool_shape``: (blocks, L,
+  block*KV*hd/128, 128), a block's (block, KV, hd) values in lane-wide
+  rows (SERVING.md §3);
 * the **discrete-time simulator** (`serve/scheduler.py`) uses the
   occupancy API (``insert`` / ``hit_fraction`` / ``touch_decode``) to
   model residency decay without any arrays — subsuming the old

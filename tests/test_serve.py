@@ -122,6 +122,41 @@ def test_engine_matches_reference(smoke_lm, mode):
     assert done[0].out == ref
 
 
+@pytest.mark.parametrize("arch", ["starcoder2-3b", "granite-3-2b"])
+def test_pool_blocks_hold_the_dense_cache(arch):
+    """The blocks an admission and the decode steps write into the pools,
+    unflattened to (L, block, KV, hd), hold what the dense executor's
+    ``prefill_step``/``decode_step`` cache holds at the same positions,
+    for KV x hd of 2 x 32 (a block row spans heads) and 4 x 32."""
+    from repro.configs import get_config, smoke_config
+    from repro.models import model as M_
+    from repro.serve.engine import GenRequest, InferenceEngine
+    cfg = smoke_config(get_config(arch)).replace(
+        n_layers=2, d_model=128, d_ff=256, vocab_size=256)
+    params = M_.init_params(cfg, jax.random.PRNGKey(1))
+    L, KV, hd, block = cfg.n_layers, cfg.n_kv_heads, cfg.hd, 8
+    prompt = np.random.default_rng(3).integers(1, 97, 11, dtype=np.int32)
+    engs = {paged: InferenceEngine(cfg, params, max_batch=2, max_seq=64,
+                                   block_size=block, paged=paged)
+            for paged in (True, False)}
+    for eng in engs.values():
+        req = GenRequest(rid=0, tokens=prompt, max_new=8)
+        eng.submit(req)
+        while len(req.out) < 7:             # prompt 0..10 at admission,
+            eng.core.step()                 # then 11..16 by decode
+    ex, dense = engs[True].executor, engs[False].executor
+    (s,) = [s for s in ex.slots if s is not None]
+    assert s.pos == 17
+    n = -(-s.pos // block)
+    ids = jnp.asarray(ex.table[s.idx, :n])
+    for pool, key in ((ex.k_pool, "k"), (ex.v_pool, "v")):
+        got = (pool[ids].reshape(n, L, block, KV, hd)
+               .transpose(1, 0, 2, 3, 4).reshape(L, n * block, KV, hd))
+        want = dense.cache[key][:, s.idx]
+        np.testing.assert_array_equal(np.asarray(got[:, :s.pos], np.float32),
+                                      np.asarray(want[:, :s.pos], np.float32))
+
+
 def test_engine_early_exit_and_per_step_admission(smoke_lm):
     """A short request frees its slot mid-run; the queued request is
     admitted into it while the long request is still decoding."""

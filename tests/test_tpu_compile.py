@@ -7,6 +7,8 @@ primitives, memory spaces, programs that do not fit). The topology is
 described inside a module fixture, so importing this file touches no
 TPU library, and the tests skip where no topology can be described.
 """
+import re
+
 import numpy as np
 import pytest
 
@@ -143,13 +145,24 @@ def test_sim_scan_body_selects_not_gathers(one_chip, alg):
     assert 0 < body["fusion"] <= SCAN_FUSIONS[alg], body
 
 
+def _two_layers(one_chip, arch):
+    """``arch`` at its published widths, cut to 2 layers, with abstract
+    bf16 parameters on the chip."""
+    from repro.models import model as M_
+    cfg = get_config(arch).replace(n_layers=2)
+    return cfg, _on(one_chip, M_.abstract_params(cfg))
+
+
 @pytest.fixture(scope="module")
 def starcoder(one_chip):
-    """starcoder2-3b at its published widths, cut to 2 layers, with
-    abstract bf16 parameters on the chip."""
-    from repro.models import model as M_
-    cfg = get_config("starcoder2-3b").replace(n_layers=2)
-    return cfg, _on(one_chip, M_.abstract_params(cfg))
+    return _two_layers(one_chip, "starcoder2-3b")
+
+
+def _whole_pool_copies(hlo: str, shape: tuple) -> list:
+    """The ``copy`` instructions of compiled HLO text whose result is an
+    array of the whole pool's ``shape``."""
+    dims = ",".join(map(str, shape))
+    return re.findall(rf"%\S+ = \w+\[{dims}\]\{{[^}}]*\}} copy\(", hlo)
 
 
 def test_starcoder2_prefill_compiles(one_chip, starcoder):
@@ -171,19 +184,22 @@ def test_starcoder2_prefill_compiles(one_chip, starcoder):
     assert compiled.memory_analysis() is not None
 
 
-def test_starcoder2_paged_decode_compiles(one_chip, starcoder):
+@pytest.mark.parametrize("arch", ["starcoder2-3b", "granite-3-2b"])
+def test_starcoder2_paged_decode_compiles(one_chip, arch):
+    """The paged decode step for KV x hd of 2 x 128 and 8 x 64 writes its
+    blocks into the donated pools in place: no copy of a whole pool
+    (pools shaped (P, L, block, KV, hd) took four or six a step)."""
     import jax
 
     from repro.models import decode as D_
     from repro.sharding.ctx import trivial_ctx
-    cfg, params = starcoder
+    cfg, params = _two_layers(one_chip, arch)
     ctx = trivial_ctx()
     B, max_seq, block = 8, 1024, 16
     nb = max_seq // block
     P = 1 + nb * (B + 2)
-    pool = jax.ShapeDtypeStruct(
-        (P, cfg.n_layers, block, cfg.n_kv_heads, cfg.hd), cfg.dtype,
-        sharding=one_chip)
+    shape = D_.pool_shape(P, cfg.n_layers, block, cfg.n_kv_heads, cfg.hd)
+    pool = jax.ShapeDtypeStruct(shape, cfg.dtype, sharding=one_chip)
     ints = _on(one_chip, (np.zeros((B, nb), np.int32),
                           np.zeros((B,), np.int32),
                           np.zeros((B,), np.int32)))
@@ -191,6 +207,27 @@ def test_starcoder2_paged_decode_compiles(one_chip, starcoder):
         p, kp, vp, tb, po, tk, cfg, ctx), donate_argnums=(1, 2))
     compiled = step.lower(params, pool, pool, *ints).compile()
     assert compiled.memory_analysis() is not None
+    assert not _whole_pool_copies(compiled.as_text(), shape)
+
+
+@pytest.mark.parametrize("donated,most", [(False, 1), (True, 0)])
+def test_paged_admission_write_copies(one_chip, donated, most):
+    """An admission's write of its prefilled blocks into a starcoder2-3b
+    pool, ``pool.at[tgt].set(blocks)``: the engine runs it eagerly, which
+    copies the pool once; donated, it writes in place."""
+    import jax
+
+    from repro.models import decode as D_
+    cfg = get_config("starcoder2-3b").replace(n_layers=2)
+    n = 40                               # a 640-token prompt's blocks
+    shape = D_.pool_shape(8000, cfg.n_layers, 16, cfg.n_kv_heads, cfg.hd)
+    pool, blocks = (jax.ShapeDtypeStruct(s, cfg.dtype, sharding=one_chip)
+                    for s in (shape, (n,) + shape[1:]))
+    tgt = _on(one_chip, np.zeros((n,), np.int32))
+    write = jax.jit(lambda p, t, b: p.at[t].set(b),
+                    donate_argnums=(0,) if donated else ())
+    hlo = write.lower(pool, tgt, blocks).compile().as_text()
+    assert len(_whole_pool_copies(hlo, shape)) <= most
 
 
 def test_starcoder2_weight_draw_compiles(one_chip):
